@@ -75,9 +75,11 @@ def pack_records(
 
     Rows redirected to the same record share one slot (``returned`` file
     ids key the dedup — exactly-once makes them distinct within an epoch,
-    but the pack stays correct for any index pattern). Slot rows are
-    padded to a multiple of ``row_pad`` columns (128 on real TPUs — the
-    lane width the kernel DMAs in; small on interpret backends).
+    but the pack stays correct for any index pattern). ``slot_tokens`` is
+    ``(num_slots, 1, Lp)``: the unit middle axis is the layout the
+    compiled gather tiles (``kernels/chunk_gather``). Slot rows are padded
+    to a multiple of ``row_pad`` columns (128 on real TPUs — the lane width
+    the kernel DMAs in; small on interpret backends).
     """
     n_rows = len(records)
     if returned is not None and len(returned) == n_rows:
@@ -89,12 +91,12 @@ def pack_records(
         inv = np.arange(n_rows)
     full = seq_len + 1
     lp = round_up(full, row_pad)
-    slot_tokens = np.full((len(first), lp), pad_id, dtype=np.int32)
+    slot_tokens = np.full((len(first), 1, lp), pad_id, dtype=np.int32)
     lens = np.zeros(len(first), dtype=np.int32)
     for s, r in enumerate(first):
         rec = records[int(r)]
         n = min(rec.shape[0], full)
-        slot_tokens[s, :n] = rec[:n]
+        slot_tokens[s, 0, :n] = rec[:n]
         lens[s] = n
     return slot_tokens, lens, inv.astype(np.int32)
 

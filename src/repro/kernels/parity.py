@@ -93,14 +93,15 @@ KERNELS: dict[str, dict] = {
         "tols": {"float32": 2e-4, "bfloat16": 5e-2},
     },
     "chunk_gather": {
-        # (num_slots, L, B)
+        # (num_slots, L, B) — slot buffer (num_slots, 1, L)
         "shapes": [(64, 128, 16), (32, 256, 8), (16, 64, 32), (128, 512, 4)],
         "quick_shapes": [(64, 128, 16)],
         "tols": {"int32": 0.0},
     },
     "chunk_gather_train": {
-        # (num_slots, seq_len, B) — slot rows lane-padded like the packer
-        "shapes": [(64, 128, 16), (32, 100, 8), (16, 64, 32)],
+        # (num_slots, seq_len, B) — slot rows lane-padded like the packer;
+        # (4, 2048, 4) is the one-chip smoke run's batch
+        "shapes": [(64, 128, 16), (32, 100, 8), (16, 64, 32), (4, 2048, 4)],
         "quick_shapes": [(64, 128, 16)],
         "tols": {"int32": 0.0},
     },
@@ -145,7 +146,7 @@ def make_inputs(case: KernelCase, seed: int = 0) -> tuple:
         return x, dts, a, b_, c
     if k == "chunk_gather":
         slots, length, batch = case.shape
-        ct = jnp.asarray(rng.integers(1, 1000, (slots, length)), jnp.int32)
+        ct = jnp.asarray(rng.integers(1, 1000, (slots, 1, length)), jnp.int32)
         lens = jnp.asarray(rng.integers(1, length + 1, (slots,)), jnp.int32)
         idx = jnp.asarray(rng.integers(0, slots, (batch,)), jnp.int32)
         return ct, lens, idx
@@ -153,9 +154,9 @@ def make_inputs(case: KernelCase, seed: int = 0) -> tuple:
         slots, seq_len, batch = case.shape
         lp = round_up(seq_len + 1, 128)
         lens = rng.integers(1, seq_len + 2, (slots,))
-        ct = np.zeros((slots, lp), np.int32)
+        ct = np.zeros((slots, 1, lp), np.int32)
         for i, n in enumerate(lens):
-            ct[i, :n] = rng.integers(1, 1000, n)
+            ct[i, 0, :n] = rng.integers(1, 1000, n)
         idx = jnp.asarray(rng.integers(0, slots, (batch,)), jnp.int32)
         return jnp.asarray(ct), jnp.asarray(lens, jnp.int32), idx
     raise ValueError(f"unknown kernel {k!r}")

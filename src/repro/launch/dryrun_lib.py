@@ -314,8 +314,6 @@ def run_cell(
 
     compile_s = time.time() - t0
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per computation
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     memory = {
         k: float(getattr(mem, k, 0.0))

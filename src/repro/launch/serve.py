@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from ..configs import get_config, list_archs, reduced
 from ..models import build_model, split_params
 from ..train.train_step import build_decode_step, build_prefill_step
+from .compile_cache import enable_compile_cache
 
 
 def main() -> int:
@@ -45,6 +46,7 @@ def main() -> int:
     if args.arch is None:
         ap.error("--arch is required unless --list-archs is given")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if not cfg.supports_decode():
         print(f"{args.arch} is encoder-only: no autoregressive serving path")

@@ -1,9 +1,13 @@
 """Training launcher: ``--arch <id>`` selects any assigned architecture.
 
-On this CPU container the model runs at the reduced (same-family) size by
-default (``--full`` uses the full config — only sensible on real hardware);
-data always flows through the real Redox chunk store + redirection
-protocol. Checkpoints/restart and the async loader are on by default.
+By default the model runs at the reduced (same-family) size, which is what
+CPU runs use. ``--full`` takes the published widths; one TPU v5e chip
+(16 GB) fits TinyLlama-1.1B at full width with ``--full --optimizer
+adafactor --remat full --batch 4 --seq-len 2048`` (the default ``adamw``
+keeps fp32 m and v beside the fp32 master and does not fit). Data always
+flows through the real Redox chunk store + redirection protocol.
+Checkpoints/restart and the async loader are on by default. Where the
+persistent compilation cache lives is ``launch/compile_cache.py``'s call.
 
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b --steps 50
 
@@ -39,6 +43,7 @@ from ..obs import (
 from ..optim.optimizers import make_optimizer
 from ..service.transport import RedoxClient
 from ..train.train_step import build_train_step, init_train_state
+from .compile_cache import enable_compile_cache
 from .cli import (
     add_autotune_args,
     add_data_plane_args,
@@ -84,6 +89,39 @@ TRACE_TIME_MODEL = PipelineTimeModel(
 )
 
 
+@dataclasses.dataclass(frozen=True)
+class StepEvent:
+    """What ``main``'s ``on_step`` hook is handed after each train step.
+
+    ``metrics`` hold device scalars: reading one waits for the step.
+    ``started`` is ``time.perf_counter()`` just before the step was
+    dispatched, so the first event's wait includes trace and compile.
+    """
+
+    step: int        # steps taken so far (1-based)
+    batch: dict      # the batch the step consumed
+    metrics: dict
+    started: float
+    stager: object   # the DeviceStager, or None on the naive path
+
+
+def session_spec(args) -> SessionSpec:
+    """The data-plane session the trainer opens for parsed ``args``."""
+    # Seeds derive from --seed exactly as in data_service.py: protocol
+    # +2, sampler +3, dataset +5 (the historical constants at seed 0).
+    return SessionSpec(
+        policy=args.policy,
+        seed=args.seed + 2,
+        sampler_seed=args.seed + 3,
+        num_nodes=args.nodes,
+        batch_per_node=max(args.batch // args.nodes, 1),
+        seq_len=args.seq_len,
+        engine=args.engine,
+        remote_memory_limit_bytes=1_000_000,
+        fidelity=args.fidelity,
+    )
+
+
 def _local_metrics(loader, store, stager) -> MetricsRegistry:
     """Registry over a local data plane's live stats objects."""
     reg = MetricsRegistry()
@@ -103,9 +141,15 @@ def _local_metrics(loader, store, stager) -> MetricsRegistry:
     return reg
 
 
-def main() -> int:
+def main(argv=None, *, on_step=None) -> int:
+    """Run the trainer on ``argv`` (``sys.argv[1:]`` when None).
+
+    ``on_step``, if given, is called with a :class:`StepEvent` after every
+    step; callers that drive the trainer in-process (``chip_smoke.py``)
+    check losses and batches through it.
+    """
     ap = build_parser()
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.data_server is not None and args.resume_data is not None:
         ap.error("--resume-data belongs to the server with --data-server "
                  "(run data_service --resume-data there)")
@@ -117,6 +161,7 @@ def main() -> int:
         ap.error("--device-path gather requires a local data plane (ring "
                  "frames ship assembled grids); use --device-path stage")
 
+    enable_compile_cache()
     tracer = trace.enable(args.trace_capacity) if args.trace else None
 
     cfg = get_config(args.arch)
@@ -130,19 +175,7 @@ def main() -> int:
     print(f"arch={args.arch} family={cfg.family} params={cfg.param_count():,d}")
 
     workdir = Path(args.workdir or tempfile.mkdtemp(prefix=f"redox_{args.arch}_"))
-    # Seeds derive from --seed exactly as in data_service.py: protocol
-    # +2, sampler +3, dataset +5 (the historical constants at seed 0).
-    spec = SessionSpec(
-        policy=args.policy,
-        seed=args.seed + 2,
-        sampler_seed=args.seed + 3,
-        num_nodes=args.nodes,
-        batch_per_node=max(args.batch // args.nodes, 1),
-        seq_len=args.seq_len,
-        engine=args.engine,
-        remote_memory_limit_bytes=1_000_000,
-        fidelity=args.fidelity,
-    )
+    spec = session_spec(args)
     data_dir = resolve_resume_dir(ap, args.resume_data, workdir / "ckpt" / "data")
     store = None
     if args.data_server is not None:
@@ -215,6 +248,9 @@ def main() -> int:
     if start:
         state = restore_checkpoint(workdir / "ckpt", start, state)
         print(f"resumed from step {start}")
+    # Commit the state to the device the step's outputs land on: an
+    # uncommitted first state keys a second compile of the step at step 2.
+    state = jax.device_put(state, jax.devices()[0])
 
     if cfg.frontend != "none":
         print("note: stub-frontend arch — launcher trains on token records "
@@ -256,6 +292,7 @@ def main() -> int:
                 feed["loss_mask"] = jnp.concatenate(
                     [jnp.zeros((b, p), jnp.float32), feed["loss_mask"]], axis=1
                 )
+            started = time.perf_counter()
             if tracer is None:
                 state, metrics = step_fn(state, feed)
             else:
@@ -271,6 +308,8 @@ def main() -> int:
                     io_grid[r].append(by_node.get(r, StepIO()))
             step += 1
             run_steps += 1
+            if on_step is not None:
+                on_step(StepEvent(step, batch, metrics, started, stager))
             if step % 10 == 0 or step == 1:
                 print(f"step {step:4d} loss {float(metrics['loss']):.4f} "
                       f"({(time.time()-t0)/step:.2f}s/step)")

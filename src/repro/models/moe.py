@@ -23,11 +23,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:
-    shard_map = jax.shard_map  # jax >= 0.4.39
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
-
 from ..parallel.axes import shard
 from .common import Param, scaled_init
 
@@ -258,7 +253,7 @@ def moe_block_a2a(p, x, cfg):
 
     spec_x = P(dp if dp else None, "model", None)
     spec_w = P("model", None, None)
-    out = shard_map(
+    out = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec_x, spec_w, spec_w, spec_w, spec_x, spec_x),

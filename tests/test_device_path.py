@@ -37,18 +37,18 @@ class TestPackRecords:
                 np.arange(5, dtype=np.int32)]
         returned = np.asarray([40, 7, 40])  # rows 0 and 2 share a file
         slots, lens, idx = pack_records(recs, returned, seq_len=16, row_pad=8)
-        assert slots.shape == (2, 24)  # 2 unique files, 17 -> pad to 24
+        assert slots.shape == (2, 1, 24)  # 2 unique files, 17 -> pad to 24
         assert slots.dtype == np.int32 and idx.dtype == np.int32
         # np.unique sorts by file id: slot 0 = file 7, slot 1 = file 40
         np.testing.assert_array_equal(idx, [1, 0, 1])
         assert lens[0] == 9 and lens[1] == 5
-        np.testing.assert_array_equal(slots[1, :5], np.arange(5))
-        assert (slots[1, 5:] == 0).all()
+        np.testing.assert_array_equal(slots[1, 0, :5], np.arange(5))
+        assert (slots[1, 0, 5:] == 0).all()
 
     def test_length_clip_to_seq_plus_one(self):
         recs = [np.arange(100, dtype=np.int32)]
         slots, lens, idx = pack_records(recs, None, seq_len=16, row_pad=8)
-        assert lens[0] == 17 and slots.shape[1] == 24
+        assert lens[0] == 17 and slots.shape[2] == 24
 
     def test_no_returned_means_one_slot_per_row(self):
         recs = [np.arange(4, dtype=np.int32)] * 3
@@ -103,7 +103,7 @@ class TestEpochDevice:
     def test_use_kernel_false_rejects_packs(self):
         stager = DeviceStager(use_kernel=False)
         with pytest.raises(ValueError, match="cannot stage HostPacks"):
-            stager.stage(HostPack(slot_tokens=np.zeros((1, 8), np.int32)))
+            stager.stage(HostPack(slot_tokens=np.zeros((1, 1, 8), np.int32)))
 
     def test_stream_is_one_at_a_time(self, tmp_path):
         store, loader = build_loader(tmp_path)

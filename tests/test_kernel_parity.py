@@ -114,18 +114,18 @@ class TestDecodeAttentionEdges:
 class TestChunkGatherEdges:
     def test_duplicate_indices(self):
         """Redirection may serve the same slot to multiple rows in a step."""
-        ct = jnp.asarray(RNG.integers(1, 100, (8, 32)), jnp.int32)
+        ct = jnp.asarray(RNG.integers(1, 100, (8, 1, 32)), jnp.int32)
         lens = jnp.full((8,), 32, jnp.int32)
         idx = jnp.asarray([3, 3, 3, 0], jnp.int32)
         t, _ = chunk_gather(ct, lens, idx)
         np.testing.assert_array_equal(np.asarray(t[0]), np.asarray(t[1]))
-        np.testing.assert_array_equal(np.asarray(t[0]), np.asarray(ct[3]))
+        np.testing.assert_array_equal(np.asarray(t[0]), np.asarray(ct[3, 0]))
 
     def test_train_matches_host_grid_semantics(self):
         """chunk_gather_train == the loader's _to_grid slicing: tokens are
         row[:-1], targets row[1:], mask aligned to targets."""
         slots, full, b = 6, 33, 9  # seq_len 32
-        ct = jnp.asarray(RNG.integers(1, 500, (slots, 40)), jnp.int32)
+        ct = jnp.asarray(RNG.integers(1, 500, (slots, 1, 40)), jnp.int32)
         lens = jnp.asarray([1, 5, 33, 17, 40, 2], jnp.int32).clip(max=full)
         idx = jnp.asarray(RNG.integers(0, slots, (b,)), jnp.int32)
         tok, tgt, mask = chunk_gather_train(ct, lens, idx, seq_len=32, pad_id=0)
@@ -139,14 +139,14 @@ class TestChunkGatherEdges:
             assert np.asarray(mask)[r].sum() == 0
 
     def test_train_duplicate_slots_share_one_row(self):
-        ct = jnp.asarray(RNG.integers(1, 100, (8, 40)), jnp.int32)
+        ct = jnp.asarray(RNG.integers(1, 100, (8, 1, 40)), jnp.int32)
         lens = jnp.full((8,), 33, jnp.int32)
         idx = jnp.asarray([5, 5, 2, 5], jnp.int32)
         tok, tgt, _ = chunk_gather_train(ct, lens, idx, seq_len=32)
         np.testing.assert_array_equal(np.asarray(tok[0]), np.asarray(tok[1]))
         np.testing.assert_array_equal(np.asarray(tok[0]), np.asarray(tok[3]))
-        np.testing.assert_array_equal(np.asarray(tok[0]), np.asarray(ct[5, :32]))
-        np.testing.assert_array_equal(np.asarray(tgt[0]), np.asarray(ct[5, 1:33]))
+        np.testing.assert_array_equal(np.asarray(tok[0]), np.asarray(ct[5, 0, :32]))
+        np.testing.assert_array_equal(np.asarray(tgt[0]), np.asarray(ct[5, 0, 1:33]))
 
 
 # ---------------------------------------------------------- ssd_scan extras

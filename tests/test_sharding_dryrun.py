@@ -64,12 +64,13 @@ SUBPROC = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json, dataclasses, jax
     from repro.launch import dryrun_lib
+    from repro.launch.mesh import make_mesh
     from repro.configs import ARCHS, reduced, get_shape
 
     small = dataclasses.replace(get_shape("train_4k"), seq_len=256, global_batch=8)
     dryrun_lib.get_config = lambda name: reduced(ARCHS[name])
     dryrun_lib.get_shape = lambda name: small
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     out = {}
     for arch in ("tinyllama-1.1b", "deepseek-moe-16b", "zamba2-1.2b"):
         r = dryrun_lib.run_cell(arch, "train_4k", mesh)
@@ -91,9 +92,10 @@ MOE_EQ_SUBPROC = textwrap.dedent(
     from repro.models.moe import init_moe, moe_block, moe_block_a2a
     from repro.parallel.axes import ShardingRules, sharding_ctx
     from repro.parallel import sharding as shd
+    from repro.launch.mesh import make_mesh
 
     cfg = dataclasses.replace(reduced(ARCHS["deepseek-moe-16b"]), capacity_factor=16.0)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     values, _ = split_params(init_moe(RngStream(0), cfg, jnp.float32))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(4, 64, cfg.d_model)), jnp.float32)
     rules = ShardingRules(mesh, shd.activation_rules(mesh, RunConfig()))

@@ -106,3 +106,36 @@ class TestRedoxTraining:
         step = jax.jit(build_train_step(model, run, opt), donate_argnums=0)
         state, m = step(state, dummy_train_inputs(cfg, 4, 64, seed=0))
         assert np.isfinite(float(m["loss"]))
+
+
+class TestLauncherInProcess:
+    """``launch.train.main(argv, on_step=...)``: the in-process entry
+    ``chip_smoke.py`` drives, at the reduced size on the gather path."""
+
+    def test_on_step_sees_every_step_and_the_host_grids(self, tmp_path, capsys):
+        from repro.core import ChunkStore
+        from repro.launch import train
+
+        argv = ["--arch", "tinyllama-1.1b", "--steps", "3", "--ckpt-every", "10",
+                "--batch", "4", "--seq-len", "32", "--num-docs", "64",
+                "--device-path", "gather", "--workdir", str(tmp_path)]
+        events = []
+        assert train.main(argv, on_step=events.append) == 0
+        assert "done: 3 steps" in capsys.readouterr().out
+        assert [e.step for e in events] == [1, 2, 3]
+        assert all(np.isfinite(float(e.metrics["loss"])) for e in events)
+        assert all(e.stager is events[0].stager for e in events)
+        assert events[0].stager.stats.kernel_steps >= 3
+
+        # The first gathered batch == the host loader's first grids.
+        spec = train.session_spec(train.build_parser().parse_args(argv))
+        store = ChunkStore.open(tmp_path / "chunks")
+        it = RedoxLoader.from_spec(spec, store).epoch_async(0)
+        host = next(it)
+        it.close()
+        store.close()
+        first = events[0].batch
+        assert int(first["step"]) == int(host["step"]) == 0
+        for k in ("tokens", "targets", "loss_mask"):
+            np.testing.assert_array_equal(np.asarray(first[k]), host[k])
+            assert np.asarray(first[k]).dtype == host[k].dtype
