@@ -152,42 +152,34 @@ class DeviceStager:
         arrays are futures, forced only when the consumer's train step
         reads them.
         """
-        t0 = time.perf_counter()
         is_pack = "slot_tokens" in item
-        if is_pack and self.use_kernel is not False:
-            slot = jax.device_put(item["slot_tokens"], self.device)
-            lens = jax.device_put(item["lens"], self.device)
-            idx = jax.device_put(item["idx"], self.device)
-            tokens, targets, loss_mask = chunk_gather_train(
-                slot, lens, idx,
-                seq_len=int(item["seq_len"]),
-                pad_id=int(item["pad_id"]),
-                interpret=self.interpret,
+        kernel = is_pack and self.use_kernel is not False
+        if is_pack and not kernel:
+            raise ValueError(
+                "DeviceStager(use_kernel=False) cannot stage HostPacks; "
+                "feed it assembled batches (epoch_async) instead"
             )
-            moved = (
-                item["slot_tokens"].nbytes
-                + item["lens"].nbytes
-                + item["idx"].nbytes
-            )
-            self.stats.kernel_steps += 1
-        else:
-            if is_pack:
-                raise ValueError(
-                    "DeviceStager(use_kernel=False) cannot stage HostPacks; "
-                    "feed it assembled batches (epoch_async) instead"
+        keys = ("slot_tokens", "lens", "idx") if kernel else _GRID_KEYS
+        moved = sum(np.asarray(item[k]).nbytes for k in keys)
+        with trace.span("stager.stage", "stage", step=int(item.get("step", -1)),
+                        bytes=int(moved), kernel=kernel):
+            t0 = time.perf_counter()
+            if kernel:
+                slot = jax.device_put(item["slot_tokens"], self.device)
+                lens = jax.device_put(item["lens"], self.device)
+                idx = jax.device_put(item["idx"], self.device)
+                tokens, targets, loss_mask = chunk_gather_train(
+                    slot, lens, idx,
+                    seq_len=int(item["seq_len"]),
+                    pad_id=int(item["pad_id"]),
+                    interpret=self.interpret,
                 )
-            tokens = jax.device_put(item["tokens"], self.device)
-            targets = jax.device_put(item["targets"], self.device)
-            loss_mask = jax.device_put(item["loss_mask"], self.device)
-            moved = sum(np.asarray(item[k]).nbytes for k in _GRID_KEYS)
-        stage_s = time.perf_counter() - t0
-        tracer = trace.get()
-        if tracer is not None:
-            tracer.complete(
-                "stager.stage", "stage", t0, stage_s,
-                {"step": int(item.get("step", -1)), "bytes": int(moved),
-                 "kernel": bool(is_pack and self.use_kernel is not False)},
-            )
+                self.stats.kernel_steps += 1
+            else:
+                tokens = jax.device_put(item["tokens"], self.device)
+                targets = jax.device_put(item["targets"], self.device)
+                loss_mask = jax.device_put(item["loss_mask"], self.device)
+            stage_s = time.perf_counter() - t0
         # Copy the StepIO entries before annotating: replay-engine batches
         # share them with the EpochPlan, which must stay reusable.
         io = {
@@ -266,13 +258,13 @@ class DeviceStager:
         t.start()
         try:
             while True:
-                t0 = time.perf_counter()
-                item = q.get()
-                wait = time.perf_counter() - t0
-                tracer = trace.get()
-                if tracer is not None:
-                    # The slice of staging the double buffer failed to hide.
-                    tracer.complete("stager.wait", "stage", t0, wait)
+                # The slice of staging the double buffer failed to hide.
+                with trace.span("stager.wait", "stage") as span:
+                    t0 = time.perf_counter()
+                    item = q.get()
+                    wait = time.perf_counter() - t0
+                    if item is not end:
+                        span.set(step=int(item.get("step", -1)))
                 if item is end:
                     break
                 with self._lock:

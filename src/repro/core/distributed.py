@@ -687,40 +687,34 @@ class Cluster:
                         break
                 elif step >= num_steps:
                     break
-                tracer = trace.get()
-                t0 = time.perf_counter() if tracer is not None else 0.0
-                io_by_node: dict[int, StepIO] = {}
-                if recorder is not None:
-                    recorder.begin_step(step - start_step)
-                returned: list[np.ndarray] = []
-                payloads: "list | None" = [] if collect_payloads else None
-                for r in range(self.num_nodes):
-                    if self.failed[r]:
-                        returned.append(np.empty(0, dtype=np.int64))
-                        continue
-                    lo, hi = self._step_bounds(r, batch_per_node)
-                    if engine == "step":
-                        ret = self.access_step(r, lo, hi, io_by_node, payloads=payloads)
-                    else:
-                        ret = np.empty(hi - lo, dtype=np.int64)
-                        for pos in range(lo, hi):
-                            f, data = self.access(
-                                r, pos, int(self.sequences[r][pos]), io_by_node
-                            )
-                            ret[pos - lo] = f
-                            if payloads is not None:
-                                payloads.append(data)
-                    returned.append(ret)
-                if recorder is not None:
-                    recorder.end_step(step - start_step, returned, io_by_node)
-                self.current_step = step + 1
-                if tracer is not None:
-                    # Spans cover production only — consumer time between
-                    # yields must not pollute the proto stage.
-                    tracer.complete(
-                        "proto.step", "proto", t0,
-                        time.perf_counter() - t0, {"step": step},
-                    )
+                # The span covers production only — consumer time between
+                # yields must not pollute the proto stage.
+                with trace.span("proto.step", "proto", step=int(step)):
+                    io_by_node: dict[int, StepIO] = {}
+                    if recorder is not None:
+                        recorder.begin_step(step - start_step)
+                    returned: list[np.ndarray] = []
+                    payloads: "list | None" = [] if collect_payloads else None
+                    for r in range(self.num_nodes):
+                        if self.failed[r]:
+                            returned.append(np.empty(0, dtype=np.int64))
+                            continue
+                        lo, hi = self._step_bounds(r, batch_per_node)
+                        if engine == "step":
+                            ret = self.access_step(r, lo, hi, io_by_node, payloads=payloads)
+                        else:
+                            ret = np.empty(hi - lo, dtype=np.int64)
+                            for pos in range(lo, hi):
+                                f, data = self.access(
+                                    r, pos, int(self.sequences[r][pos]), io_by_node
+                                )
+                                ret[pos - lo] = f
+                                if payloads is not None:
+                                    payloads.append(data)
+                        returned.append(ret)
+                    if recorder is not None:
+                        recorder.end_step(step - start_step, returned, io_by_node)
+                    self.current_step = step + 1
                 yield step, returned, payloads, io_by_node
                 step += 1
             if stepping == "floor_tail":
@@ -853,41 +847,39 @@ class Cluster:
                 for loc, data in rm._payloads.items():
                     pool[int(rm._loc_file[loc])] = data
         for step in range(plan.num_steps + (1 if plan.has_tail else 0)):
-            tracer = trace.get()
-            t0 = time.perf_counter() if tracer is not None else 0.0
-            io_by_node = plan.step_io(step)
-            if store is not None:
-                for li in range(*plan.load_range(step)):
-                    owner = int(plan.load_owner[li])
-                    t0 = time.perf_counter()
-                    records = dict(store.read_chunk(int(plan.load_chunk[li])))
-                    wait = time.perf_counter() - t0
-                    st = self.nodes[owner].stats
-                    st.read_wait_s += wait
-                    if owner in io_by_node:
-                        io_by_node[owner].read_wait_s += wait
-                    st.peak_inflight_reads = max(
-                        st.peak_inflight_reads, store.backend_stats.peak_inflight
-                    )
-                    for f in plan.load_files(li).tolist():
-                        pool[f] = records[f]
-            returned = plan.step_returned(step)
-            if step >= plan.num_steps:
-                if store is not None:  # tail payloads are read but never yielded
-                    for ret in returned:
-                        for f in ret.tolist():
-                            pool.pop(f, None)
+            tail = step >= plan.num_steps
+            # The span covers production only, the step's chunk reads
+            # (``store.read_chunk``) nested inside it.
+            with trace.span("replay.step", "proto", step=plan.start_step + step):
+                io_by_node = plan.step_io(step)
+                if store is not None:
+                    for li in range(*plan.load_range(step)):
+                        owner = int(plan.load_owner[li])
+                        t_read = time.perf_counter()
+                        records = dict(store.read_chunk(int(plan.load_chunk[li])))
+                        wait = time.perf_counter() - t_read
+                        st = self.nodes[owner].stats
+                        st.read_wait_s += wait
+                        if owner in io_by_node:
+                            io_by_node[owner].read_wait_s += wait
+                        st.peak_inflight_reads = max(
+                            st.peak_inflight_reads, store.backend_stats.peak_inflight
+                        )
+                        for f in plan.load_files(li).tolist():
+                            pool[f] = records[f]
+                returned = plan.step_returned(step)
+                payloads = None
+                if tail:
+                    if store is not None:  # tail payloads are read but never yielded
+                        for ret in returned:
+                            for f in ret.tolist():
+                                pool.pop(f, None)
+                elif collect_payloads:
+                    payloads = [
+                        pool.pop(int(f)) for ret in returned for f in ret.tolist()
+                    ]
+            if tail:
                 break
-            payloads = None
-            if collect_payloads:
-                payloads = [
-                    pool.pop(int(f)) for ret in returned for f in ret.tolist()
-                ]
-            if tracer is not None:
-                tracer.complete(
-                    "replay.step", "proto", t0, time.perf_counter() - t0,
-                    {"step": plan.start_step + step},
-                )
             # Suffix plans (EpochPlanner.plan_from) are step-indexed from
             # their resume point; yield absolute step numbers either way.
             yield plan.start_step + step, returned, payloads, io_by_node

@@ -322,33 +322,29 @@ class EpochPlanner:
         failures: "dict[int, int] | None" = None,
         joins: "dict[int, int] | None" = None,
     ) -> EpochPlan:
-        t0 = time.perf_counter()
-        shadow = self.cluster.planning_clone()
-        initial_nodes = shadow.num_nodes
-        rec = PlanRecorder()
-        steps = 0
-        for step, _, _, _ in shadow.epoch_stream(
-            sampler, epoch, batch_per_node,
-            stepping=stepping, recorder=rec, failures=failures, joins=joins,
-        ):
-            steps = step + 1
-        plan = EpochPlan.from_recorder(
-            rec,
-            epoch=epoch,
-            batch_per_node=batch_per_node,
-            num_nodes=shadow.num_nodes,
-            stepping=stepping,
-            num_steps=steps,
-            node_stats=[n.stats for n in shadow.nodes],
-            joined_nodes=shadow.num_nodes - initial_nodes,
-        )
-        plan.stats.plan_time_s = time.perf_counter() - t0
-        tracer = trace.get()
-        if tracer is not None:
-            tracer.complete(
-                "planner.plan", "plan", t0, plan.stats.plan_time_s,
-                {"epoch": epoch, "steps": steps},
+        with trace.span("planner.plan", "plan", epoch=epoch) as span:
+            t0 = time.perf_counter()
+            shadow = self.cluster.planning_clone()
+            initial_nodes = shadow.num_nodes
+            rec = PlanRecorder()
+            steps = 0
+            for step, _, _, _ in shadow.epoch_stream(
+                sampler, epoch, batch_per_node,
+                stepping=stepping, recorder=rec, failures=failures, joins=joins,
+            ):
+                steps = step + 1
+            plan = EpochPlan.from_recorder(
+                rec,
+                epoch=epoch,
+                batch_per_node=batch_per_node,
+                num_nodes=shadow.num_nodes,
+                stepping=stepping,
+                num_steps=steps,
+                node_stats=[n.stats for n in shadow.nodes],
+                joined_nodes=shadow.num_nodes - initial_nodes,
             )
+            plan.stats.plan_time_s = time.perf_counter() - t0
+            span.set(steps=steps)
         return plan
 
     def plan_from(
@@ -368,39 +364,35 @@ class EpochPlanner:
         schedules are keyed by absolute step, so passing the original
         ``failures``/``joins`` dicts replays the scenario's suffix events.
         """
-        t0 = time.perf_counter()
-        shadow = Cluster.restore(snapshot, plan=self.cluster.plan)
-        initial_nodes = shadow.num_nodes
-        batch = snapshot.grid.get("batch_per_node")
-        stepping = snapshot.grid.get("stepping") or "ceil"
-        assert batch is not None, "snapshot carries no step grid to re-plan on"
-        rec = PlanRecorder()
-        steps = 0
-        for step, _, _, _ in shadow.epoch_stream(
-            None, snapshot.epoch, batch,
-            stepping=stepping, recorder=rec, failures=failures, joins=joins,
-            start_step=snapshot.step, resume=True,
-        ):
-            steps = step - snapshot.step + 1
-        plan = EpochPlan.from_recorder(
-            rec,
-            epoch=snapshot.epoch,
-            batch_per_node=batch,
-            num_nodes=shadow.num_nodes,
-            stepping=stepping,
-            num_steps=steps,
-            node_stats=[n.stats for n in shadow.nodes],
-            start_step=snapshot.step,
-            joined_nodes=shadow.num_nodes - initial_nodes,
-        )
-        plan.stats.plan_time_s = time.perf_counter() - t0
-        tracer = trace.get()
-        if tracer is not None:
-            tracer.complete(
-                "planner.plan_from", "plan", t0, plan.stats.plan_time_s,
-                {"epoch": snapshot.epoch, "start_step": snapshot.step,
-                 "steps": steps},
+        with trace.span("planner.plan_from", "plan", epoch=snapshot.epoch,
+                        start_step=snapshot.step) as span:
+            t0 = time.perf_counter()
+            shadow = Cluster.restore(snapshot, plan=self.cluster.plan)
+            initial_nodes = shadow.num_nodes
+            batch = snapshot.grid.get("batch_per_node")
+            stepping = snapshot.grid.get("stepping") or "ceil"
+            assert batch is not None, "snapshot carries no step grid to re-plan on"
+            rec = PlanRecorder()
+            steps = 0
+            for step, _, _, _ in shadow.epoch_stream(
+                None, snapshot.epoch, batch,
+                stepping=stepping, recorder=rec, failures=failures, joins=joins,
+                start_step=snapshot.step, resume=True,
+            ):
+                steps = step - snapshot.step + 1
+            plan = EpochPlan.from_recorder(
+                rec,
+                epoch=snapshot.epoch,
+                batch_per_node=batch,
+                num_nodes=shadow.num_nodes,
+                stepping=stepping,
+                num_steps=steps,
+                node_stats=[n.stats for n in shadow.nodes],
+                start_step=snapshot.step,
+                joined_nodes=shadow.num_nodes - initial_nodes,
             )
+            plan.stats.plan_time_s = time.perf_counter() - t0
+            span.set(steps=steps)
         return plan
 
     def state_at(

@@ -190,8 +190,9 @@ class StepIO(StatsDict):
 class DeviceStats(StatsDict):
     """Host→device staging counters for one :class:`DeviceStager` stream.
 
-    ``stage_s`` is wall time the staging thread spent assembling + shipping
-    batches (decode/pack, ``device_put``, gather-kernel dispatch);
+    ``stage_s`` is wall time the staging thread spent shipping batches
+    (``device_put`` and the gather kernel's dispatch; the decode and pack
+    before it are the loader's, timed by its ``loader.pack`` span);
     ``wait_s`` is the consumer time actually blocked on a staged batch —
     the part of staging the double buffer failed to hide.
     ``overlap_fraction`` is therefore the headline number: 1.0 means the

@@ -92,6 +92,7 @@ def chunk_gather(
             jax.ShapeDtypeStruct((b, 1, l), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
+        name="chunk_gather",
     )(indices, record_lens, chunk_tokens)
     return tokens, mask
 
@@ -154,5 +155,8 @@ def chunk_gather_train(
             jax.ShapeDtypeStruct((b, 1, seq_len), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
+        # A stable name for the kernel's op in the compiled module and in
+        # device traces.
+        name="chunk_gather_train",
     )(indices, record_lens, chunk_tokens)
     return tokens, targets, mask

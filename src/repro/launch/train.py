@@ -38,6 +38,7 @@ from ..obs import (
     attribution,
     format_report,
     model_columns,
+    programs,
     trace,
 )
 from ..optim.optimizers import make_optimizer
@@ -292,16 +293,15 @@ def main(argv=None, *, on_step=None) -> int:
                 feed["loss_mask"] = jnp.concatenate(
                     [jnp.zeros((b, p), jnp.float32), feed["loss_mask"]], axis=1
                 )
+            if run_steps == 0:
+                # for tools that map a profile's device ops to name scopes
+                programs.note("train_step", step_fn, state, feed)
             started = time.perf_counter()
-            if tracer is None:
+            # The step's dispatch only: the device runs it asynchronously,
+            # so tracing adds no sync. The device's own time is the
+            # profiler's (``jit_train_step`` on its ops line).
+            with trace.span("train.step", "compute", step=step):
                 state, metrics = step_fn(state, feed)
-            else:
-                # Force the step inside the span so "compute" reflects real
-                # device time, not dispatch (tracing is opt-in, so the
-                # pipeline bubble this sync adds is acceptable).
-                with trace.span("train.step", "compute", step=step):
-                    state, metrics = step_fn(state, feed)
-                    jax.block_until_ready(metrics)
             if io_grid is not None:
                 by_node = batch.get("io_by_node") or {}
                 for r in range(spec.num_nodes):
@@ -311,7 +311,10 @@ def main(argv=None, *, on_step=None) -> int:
             if on_step is not None:
                 on_step(StepEvent(step, batch, metrics, started, stager))
             if step % 10 == 0 or step == 1:
-                print(f"step {step:4d} loss {float(metrics['loss']):.4f} "
+                # Reading the loss waits for the step to finish on the device.
+                with trace.span("train.loss_sync", "compute", step=step):
+                    loss = float(metrics["loss"])
+                print(f"step {step:4d} loss {loss:.4f} "
                       f"({(time.time()-t0)/step:.2f}s/step)")
             if step % args.ckpt_every == 0:
                 ckpt.save(step, state)

@@ -85,16 +85,19 @@ def _apply_block(kind, p, x, cfg, state=None):
     consumers (it is still produced — XLA DCEs it when unused).
     """
     aux = jnp.zeros((), jnp.float32)
-    if kind in ("attn_mlp", "attn_dense_moe", "shared_attn"):
-        h, kv = attention_block(p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), cfg)
+    if kind in ("attn_mlp", "attn_dense_moe", "shared_attn", "attn_moe"):
+        # Named scopes mark the block's parts in the compiled step's op
+        # metadata, so a device trace can split the step by them.
+        with jax.named_scope("attention"):
+            h, kv = attention_block(p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), cfg)
         x = x + h
-        x = x + mlp_block(p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps))
-        return x, kv, aux
-    if kind == "attn_moe":
-        h, kv = attention_block(p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), cfg)
-        x = x + h
+        if kind != "attn_moe":
+            with jax.named_scope("mlp"):
+                h = mlp_block(p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps))
+            return x + h, kv, aux
         moe_fn = moe_block_a2a if cfg.moe_impl == "a2a" else moe_block
-        h, aux = moe_fn(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg)
+        with jax.named_scope("moe"):
+            h, aux = moe_fn(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg)
         return x + h, kv, aux
     if kind == "mamba2":
         h, st = mamba2_block(p["mixer"], rms_norm(x, p["ln"], cfg.norm_eps), cfg,
@@ -261,11 +264,12 @@ class Model:
 
     def _logits(self, values, x):
         cfg = self.cfg
-        x = rms_norm(x, values["final_norm"], cfg.norm_eps)
-        head = (
-            values["embed"].T if cfg.tie_embeddings else values["lm_head"]
-        )
-        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+        with jax.named_scope("head_loss"):
+            x = rms_norm(x, values["final_norm"], cfg.norm_eps)
+            head = (
+                values["embed"].T if cfg.tie_embeddings else values["lm_head"]
+            )
+            logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
         return shard(logits, "batch", None, "vocab")
 
     # ------------------------------------------------------------ forward

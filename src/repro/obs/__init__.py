@@ -11,8 +11,12 @@ Three pieces (see DESIGN.md §13):
   ``exposition()``, served live over the transport ``metrics`` RPC.
 * :mod:`repro.obs.report` — fold a trace into an overlap-aware per-stage
   wall-time breakdown and compare against the DESIGN §6 time model.
+
+:mod:`repro.obs.programs` keeps the jitted programs a launcher runs, so a
+device profile's ops can be mapped back to the name scopes they came from.
 """
 
+from repro.obs import programs
 from repro.obs import tracer as trace
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.report import STAGES, attribution, format_report, model_columns
@@ -28,6 +32,7 @@ __all__ = [
     "attribution",
     "format_report",
     "model_columns",
+    "programs",
     "trace",
     "tracing",
 ]

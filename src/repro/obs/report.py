@@ -42,7 +42,7 @@ __all__ = [
 #: Attribution priority, highest first. Event categories not listed fold
 #: into ``other``.
 STAGES = (
-    "compute",   # train_step on the consumer thread
+    "compute",   # train step dispatch + the loss sync on the consumer thread
     "stage",     # host->device staging + consumer wait on staged batches
     "ring",      # shared-memory ring write/read (incl. consumer poll wait)
     "decode",    # record decode + grid/pack assembly

@@ -11,10 +11,20 @@ API::
         ...                      # timed while a tracer is installed
     trace.instant("residency.evict", "read", chunk=k)
 
-and pay only a module-attribute load + ``None`` check when tracing is off
-— the disabled path allocates nothing and takes no locks, which is what
-keeps the instrumented hot loops (protocol step, ring write, staging)
-inside the <5% overhead budget pinned by ``tests/test_obs.py``.
+and pay only a module-attribute load, a ``None`` check and the profiler's
+own enabled test when tracing is off — the disabled path allocates nothing
+and takes no locks, which is what keeps the instrumented hot loops
+(protocol step, ring write, staging) inside the <5% overhead budget pinned
+by ``tests/test_obs.py``.
+
+While a ``jax.profiler`` trace is active, every span is also written as a
+``jax.profiler.TraceAnnotation(name, **args)``, whether or not a ring is
+installed: the program's spans then land on the profiler's host plane, on
+the clock its device ops use, and each span name's count and total
+duration are summed in-process (:func:`profiled`), so a tool that runs the
+profiler can read them without parsing its file. The bridge is looked up
+only once some other module has imported JAX, so processes that never
+touch JAX (service children) stay free of it.
 
 Design notes:
 
@@ -32,6 +42,7 @@ Design notes:
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 from collections import deque
@@ -43,6 +54,7 @@ __all__ = [
     "enable",
     "get",
     "instant",
+    "profiled",
     "span",
     "tracing",
 ]
@@ -59,31 +71,52 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: records a complete event when the ``with`` exits."""
+    """One live span: records a complete event in the ring (when a tracer
+    is installed) and a profiler annotation (when a profiler trace is
+    active) around the ``with`` body."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args):
+    def __init__(self, tracer: "Tracer | None", name: str, cat: str, args,
+                 annotate: bool = False):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._annotation = (
+            _annotation_class(name, **(args or {})) if annotate else None
+        )
 
     def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        self._tracer.complete(
-            self.name, self.cat, self._t0, t1 - self._t0, self.args
-        )
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            _add_profiled(self.name, t1 - self._t0)
+        if self._tracer is not None:
+            self._tracer.complete(
+                self.name, self.cat, self._t0, t1 - self._t0, self.args
+            )
         return False
+
+    def set(self, **args) -> None:
+        """Add args known only inside the span (before it exits)."""
+        self.args = {**(self.args or {}), **args}
+        if self._annotation is not None:
+            self._annotation.set_metadata(**args)
 
 
 class Tracer:
@@ -114,7 +147,7 @@ class Tracer:
         return tid
 
     def span(self, name: str, cat: str = "", **args) -> _Span:
-        return _Span(self, name, cat, args or None)
+        return _Span(self, name, cat, args or None, _profiling())
 
     def complete(
         self, name: str, cat: str, t0: float, dur: float, args=None
@@ -200,6 +233,48 @@ def _jsonable(v):
 # ------------------------------------------------------------- module state
 _active: "Tracer | None" = None
 
+# ``TraceAnnotation`` and its enabled test, looked up once JAX is loaded.
+_annotation_class = None
+_annotation_enabled = None
+
+
+def _profiling() -> bool:
+    """True while a ``jax.profiler`` trace is active in this process."""
+    enabled = _annotation_enabled
+    if enabled is None:
+        if "jax" not in sys.modules:
+            return False
+        enabled = _load_bridge()
+    return enabled()
+
+
+def _load_bridge():
+    global _annotation_class, _annotation_enabled
+    from jax.profiler import TraceAnnotation
+
+    _annotation_class = TraceAnnotation
+    _annotation_enabled = TraceAnnotation.is_enabled
+    return _annotation_enabled
+
+
+# Count and total seconds of each span name that ran while a profiler
+# trace was active (its annotation written).
+_profiled: "dict[str, tuple[int, float]]" = {}
+_profiled_lock = threading.Lock()
+
+
+def _add_profiled(name: str, dur: float) -> None:
+    with _profiled_lock:
+        n, total = _profiled.get(name, (0, 0.0))
+        _profiled[name] = (n + 1, total + dur)
+
+
+def profiled() -> "dict[str, tuple[int, float]]":
+    """``{span name: (count, seconds)}`` of the spans begun while a
+    ``jax.profiler`` trace was active in this process."""
+    with _profiled_lock:
+        return dict(_profiled)
+
 
 def enable(capacity: int = 65536) -> Tracer:
     """Install (and return) the process-wide tracer. Idempotent-ish: a
@@ -222,15 +297,18 @@ def get() -> "Tracer | None":
 
 
 def span(name: str, cat: str = "", **args):
-    """Module-level span: a real span when tracing is on, a shared no-op
-    context manager otherwise (the hot-path fast exit)."""
+    """Module-level span: a real span when a tracer is installed or a
+    profiler trace is active, a shared no-op context manager otherwise
+    (the hot-path fast exit)."""
     t = _active
-    if t is None:
+    annotate = _profiling()
+    if t is None and not annotate:
         return _NULL_SPAN
-    return _Span(t, name, cat, args or None)
+    return _Span(t, name, cat, args or None, annotate)
 
 
 def instant(name: str, cat: str = "", **args) -> None:
+    """A zero-duration point event, in the ring only."""
     t = _active
     if t is not None:
         t.instant(name, cat, **args)

@@ -318,8 +318,10 @@ class SharedResidency:
         ``[(file_id, bytes), ...]`` records, decoded at the claiming
         session's ``fidelity`` (None: the store's default)."""
         chunk = int(chunk)
-        tracer = trace.get()
-        t0 = time.perf_counter() if tracer is not None else 0.0
+        with trace.span("residency.claim", "read", chunk=chunk) as span:
+            return self._claim(job, chunk, epoch, fidelity, span)
+
+    def _claim(self, job, chunk: int, epoch, fidelity, span):
         st = self.job_stats(job)
         while True:
             with self._lock:
@@ -345,14 +347,9 @@ class SharedResidency:
             # not to retain).
             ev.wait()
         if hit:
-            records = self._decode_claim(st, chunk, payload, fidelity)
-            if tracer is not None:
-                tracer.complete(
-                    "residency.claim", "read", t0,
-                    time.perf_counter() - t0,
-                    {"chunk": chunk, "hit": True},
-                )
-            return records
+            span.set(hit=True)
+            return self._decode_claim(st, chunk, payload, fidelity)
+        span.set(hit=False)
         try:
             payload = self._read_physical(chunk)
             # Decode before insert: the first claim consumes the backend
@@ -373,11 +370,6 @@ class SharedResidency:
             if self._retain_locked(chunk):
                 self._insert_locked(job, chunk, payload, nbytes)
             ev.set()
-        if tracer is not None:
-            tracer.complete(
-                "residency.claim", "read", t0, time.perf_counter() - t0,
-                {"chunk": chunk, "hit": False},
-            )
         return records
 
     # -------------------------------------------------------------- internals

@@ -38,13 +38,16 @@ def build_train_step(model: Model, run_cfg: RunConfig, optimizer: Optimizer):
 
     def loss_fn(values, batch):
         logits, aux, _ = model.forward(values, batch, remat=run_cfg.remat)
-        loss, metrics = lm_loss(
-            logits,
-            batch["targets"],
-            batch["loss_mask"],
-            aux=aux,
-            aux_weight=cfg.router_aux_weight if cfg.moe_num_experts else 0.0,
-        )
+        # The model names its own parts (attention, mlp, head_loss); the
+        # loss joins the head's scope.
+        with jax.named_scope("head_loss"):
+            loss, metrics = lm_loss(
+                logits,
+                batch["targets"],
+                batch["loss_mask"],
+                aux=aux,
+                aux_weight=cfg.router_aux_weight if cfg.moe_num_experts else 0.0,
+            )
         return loss, metrics
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
@@ -87,10 +90,11 @@ def build_train_step(model: Model, run_cfg: RunConfig, optimizer: Optimizer):
         if run_cfg.grad_allreduce_dtype:
             dt = jnp.dtype(run_cfg.grad_allreduce_dtype)
             grads = jax.tree.map(lambda g: g.astype(dt), grads)
-        grads, gnorm = clip_by_global_norm(grads, run_cfg.grad_clip)
-        new_values, new_opt = optimizer.update(
-            grads, state["opt"], state["values"], state["step"]
-        )
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, run_cfg.grad_clip)
+            new_values, new_opt = optimizer.update(
+                grads, state["opt"], state["values"], state["step"]
+            )
         metrics = dict(metrics, loss=loss, grad_norm=gnorm)
         return (
             {"values": new_values, "opt": new_opt, "step": state["step"] + 1},

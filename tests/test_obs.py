@@ -21,6 +21,7 @@ Pins the tentpole contracts of ``repro.obs``:
 
 import json
 import time
+import warnings
 from pathlib import Path
 
 import pytest
@@ -181,6 +182,30 @@ class TestDisabledPurity:
         assert_streams_equal(got_replay, ref_replay, num_files=HARNESS_KW["n"])
 
 
+    def test_disabled_with_jax_loaded_allocates_nothing(self):
+        """With JAX loaded the span also asks the profiler whether a trace
+        is active; with none active and no ring it still hands back the
+        shared null span and keeps no memory."""
+        import tracemalloc
+
+        import jax.profiler  # noqa: F401  (the bridge is looked up once JAX is in)
+
+        assert trace.get() is None and not trace._profiling()
+        span = trace.span
+        tracemalloc.start()
+        try:
+            before = tracemalloc.take_snapshot()
+            for i in range(10_000):
+                with span("site", "read", step=i) as s:
+                    assert s is _NULL_SPAN
+            after = tracemalloc.take_snapshot()
+        finally:
+            tracemalloc.stop()
+        kept = sum(d.size_diff for d in after.compare_to(before, "filename")
+                   if d.traceback[0].filename == trace.__file__)
+        assert kept <= 0
+
+
 def _time_disabled_sites(n: int) -> float:
     span = trace.span
     t0 = time.perf_counter()
@@ -189,6 +214,132 @@ def _time_disabled_sites(n: int) -> float:
             pass
     return time.perf_counter() - t0
 
+
+# ----------------------------------------------------------- profiler bridge
+def _profiled_spans(tmp_path, body):
+    """Run ``body`` under a ``jax.profiler`` trace; the program spans on
+    the trace's host plane as ``(name, start_ns, end_ns, line, args)``."""
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        body()
+    (path,) = (tmp_path / "prof").glob("**/*.xplane.pb")
+    out = []
+    with warnings.catch_warnings():  # the stats type warns as it is read
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(str(path)).planes:
+            if plane.name.startswith("/host:"):
+                for li, line in enumerate(plane.lines):
+                    out.extend((e.name, e.start_ns, e.start_ns + e.duration_ns, li,
+                                dict(e.stats)) for e in line.events)
+    return out
+
+
+class TestProfilerBridge:
+    def test_span_lands_on_the_host_plane_with_its_args(self, tmp_path):
+        """No ring installed: the span is written for the profiler alone,
+        args and a late ``set`` included; instants stay ring-only."""
+        assert trace.get() is None
+
+        def body():
+            assert trace._profiling()
+            with trace.span("store.read_chunk", "read", chunk=3, backend="vfs"):
+                with trace.span("stager.wait", "stage") as s:
+                    s.set(step=7)
+            trace.instant("residency.evict", "read", chunk=3)
+
+        spans = {e[0]: e for e in _profiled_spans(tmp_path, body)}
+        assert spans["store.read_chunk"][4] == {"chunk": 3, "backend": "vfs"}
+        assert spans["stager.wait"][4] == {"step": 7}
+        outer, inner = spans["store.read_chunk"], spans["stager.wait"]
+        assert outer[1] <= inner[1] and inner[2] <= outer[2]
+        assert "residency.evict" not in spans
+        assert not trace._profiling()
+
+    def test_ring_and_profiler_both_record(self, tmp_path):
+        with tracing() as t:
+            def body():
+                with trace.span("loader.pack", "decode", step=2):
+                    pass
+            spans = _profiled_spans(tmp_path, body)
+        assert [e[0] for e in t.events()] == ["loader.pack"]
+        assert t.events()[0][5] == {"step": 2}
+        assert [(e[0], e[4]) for e in spans if e[0] == "loader.pack"] == [
+            ("loader.pack", {"step": 2})]
+
+    def test_every_per_step_span_reaches_the_profiler_with_its_step(self, tmp_path):
+        """A short training run on the gather path under the profiler:
+        each per-step span of the loader, the replay engine, the stager
+        and the launcher is on the host plane with its ``step``, and each
+        chunk read lies inside a ``replay.step`` span of its thread."""
+        from repro.launch import train
+
+        argv = ["--arch", "tinyllama-1.1b", "--steps", "10", "--ckpt-every", "100",
+                "--batch", "4", "--seq-len", "32", "--num-docs", "64",
+                "--device-path", "gather", "--workdir", str(tmp_path / "run")]
+        spans = _profiled_spans(tmp_path, lambda: train.main(argv))
+        per_step = ("replay.step", "loader.pack", "stager.stage", "stager.wait",
+                    "train.step", "train.loss_sync")
+        for name in per_step:
+            found = [e for e in spans if e[0] == name]
+            assert found, name
+            assert all(isinstance(e[4].get("step"), int) for e in found), name
+        assert sorted(e[4]["step"] for e in spans if e[0] == "train.step") == list(range(10))
+        assert sorted(e[4]["step"] for e in spans if e[0] == "train.loss_sync") == [1, 10]
+        steps = [e for e in spans if e[0] == "replay.step"]
+        reads = [e for e in spans if e[0] == "store.read_chunk"]
+        assert reads
+        for r in reads:
+            assert any(s[3] == r[3] and s[1] <= r[1] and r[2] <= s[2] for s in steps), r
+
+
+    def test_profiled_sums_each_name_while_the_profiler_runs(self, tmp_path, monkeypatch):
+        """While a profiler trace is active each span name's count and
+        seconds are summed in-process, alike to what its file holds; spans
+        begun before or after it are not."""
+        monkeypatch.setattr(trace, "_profiled", {})
+        with trace.span("loader.pack", "decode", step=0):
+            pass
+
+        def body():
+            for step in range(3):
+                with trace.span("loader.pack", "decode", step=step):
+                    time.sleep(0.002)
+
+        spans = _profiled_spans(tmp_path, body)
+        with trace.span("loader.pack", "decode", step=3):
+            time.sleep(0.01)
+        n, seconds = trace.profiled()["loader.pack"]
+        on_plane = [e for e in spans if e[0] == "loader.pack"]
+        assert n == len(on_plane) == 3
+        assert seconds == pytest.approx(sum(e[2] - e[1] for e in on_plane) / 1e9, abs=1e-3)
+
+
+class TestPrograms:
+    def test_a_noted_program_compiles_again_with_its_scopes(self, monkeypatch):
+        """``note`` keeps shapes, not arrays (the call may donate them);
+        ``compiled_text`` gives the module with its name scope metadata,
+        once compiled."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.obs import programs
+
+        monkeypatch.setattr(programs, "_programs", {})
+
+        def step(s, b):
+            with jax.named_scope("attention"):
+                return s * 2, jnp.tanh(b).sum()
+
+        fn = jax.jit(step, donate_argnums=0)
+        s, b = jnp.ones(4), jnp.ones((2, 4))
+        programs.note("step", fn, s, b)
+        fn(s, b)
+        assert programs.compiled_text("other") is None
+        text = programs.compiled_text("step")
+        assert text.startswith("HloModule jit_step") and "/attention/tanh" in text
+        assert programs.compiled_text("step") is text
 
 # ------------------------------------------------------------------ metrics
 class TestMetricsRegistry:
@@ -382,6 +533,36 @@ class TestAttribution:
         assert att["spans"] > 0
         covered = sum(att["exclusive_s"].values()) + att["idle_s"]
         assert covered == pytest.approx(wall, rel=0.10)
+
+
+class TestReplaySpans:
+    def test_replay_step_encloses_its_chunk_reads(self, tmp_path):
+        """On a store behind storage latency every ``replay.step`` span
+        covers the whole step: each of the step's ``store.read_chunk``
+        spans lies inside it, on the same thread."""
+        from repro.core import RedoxLoader
+        from repro.core.storage.vfs import VFSBackend
+
+        ds = SyntheticTokenDataset(96, vocab_size=97, mean_len=24, seed=3)
+        built = ds.build_store(tmp_path / "chunks", 2, num_slots=16, seed=1)
+        store = ChunkStore.open(built.root, backend=VFSBackend(latency_s=0.002))
+        spec = SessionSpec(seed=5, num_nodes=1, batch_per_node=8, seq_len=32)
+        try:
+            with tracing(capacity=1 << 16) as t:
+                for _ in RedoxLoader.from_spec(spec, store).epoch(0):
+                    pass
+        finally:
+            store.close()
+        events = t.events()
+        steps = [e for e in events if e[0] == "replay.step"]
+        reads = [e for e in events if e[0] == "store.read_chunk"]
+        assert steps and reads
+        per_step = {}
+        for name, _, ts, dur, tid, _args in reads:
+            (owner,) = [s for s in steps
+                        if s[4] == tid and s[2] <= ts and ts + dur <= s[2] + s[3]]
+            per_step[owner[5]["step"]] = per_step.get(owner[5]["step"], 0) + 1
+        assert max(per_step.values()) >= 2  # a step with several reads
 
 
 # ---------------------------------------------------------- live server RPC

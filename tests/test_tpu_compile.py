@@ -83,3 +83,27 @@ def test_chunk_gather_compiles_for_v5e(one_chip):
         slots, *_tables(one_chip), interpret=False
     ).compile()
     _assert_kernel_grids(compiled, 2048)
+
+
+def test_gather_kernel_op_keeps_its_name(one_chip):
+    """The benchmark's ``chunk_gather_roofline`` finds the kernel in a
+    device trace by the op's own instruction name inside the gather's
+    module: the compiled module holds exactly one executed op that it
+    matches, the kernel's custom call, named after ``pallas_call``'s
+    ``name``. (The tuple elements read out of it execute nothing.)"""
+    from bench.metrics import chunk_gather_roofline as reader
+
+    seq_len = 512
+    slots = jax.ShapeDtypeStruct(
+        (16, 1, round_up(seq_len + 1, 128)), jnp.int32, sharding=one_chip
+    )
+    text = chunk_gather_train.lower(
+        slots, *_tables(one_chip), seq_len=seq_len, interpret=False
+    ).compile().as_text()
+    assert text.split(",", 1)[0] == f"HloModule {reader.MODULE}"
+    ops = [line.strip().removeprefix("ROOT ") for line in text.splitlines()[1:]
+           if " = " in line and "get-tuple-element(" not in line]
+    matched = [reader.instruction(op) for op in ops
+               if reader.KERNEL.search(reader.instruction(op))]
+    assert len(matched) == 1 and matched[0].startswith("chunk_gather_train.")
+    assert "custom-call(" in next(op for op in ops if reader.instruction(op) == matched[0])

@@ -139,3 +139,41 @@ class TestLauncherInProcess:
         for k in ("tokens", "targets", "loss_mask"):
             np.testing.assert_array_equal(np.asarray(first[k]), host[k])
             assert np.asarray(first[k]).dtype == host[k].dtype
+
+
+class TestNamedScopes:
+    """The train step names its parts (attention, mlp, head_loss,
+    optimizer) as HLO metadata only: the compiled step does the same
+    work with and without the names."""
+
+    SCOPES = ("attention", "mlp", "head_loss", "optimizer")
+
+    def _compiled(self, kind="attn_mlp"):
+        name = "tinyllama-1.1b" if kind == "attn_mlp" else "deepseek-moe-16b"
+        cfg, model, run, opt, state = _setup(name, optimizer="adafactor", remat="full")
+        batch = dummy_train_inputs(cfg, 2, 32, seed=0)
+        return jax.jit(build_train_step(model, run, opt)).lower(state, batch).compile()
+
+    @staticmethod
+    def _scopes(text):
+        import re
+
+        found = set()
+        for path in re.findall(r'op_name="([^"]+)"', text):
+            for part in path.split("/"):
+                found.add(re.sub(r"^(?:[\w.-]+\()*([\w.-]+)\)*$", r"\1", part))
+        return found
+
+    def test_scopes_name_the_step_and_leave_its_flops(self, monkeypatch):
+        import contextlib
+
+        named = self._compiled()
+        assert set(self.SCOPES) <= self._scopes(named.as_text())
+        monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        plain = self._compiled()
+        assert not set(self.SCOPES) & self._scopes(plain.as_text())
+        assert named.cost_analysis()["flops"] == plain.cost_analysis()["flops"] > 0
+
+    def test_moe_kinds_name_their_experts(self):
+        scopes = self._scopes(self._compiled("attn_moe").as_text())
+        assert {"attention", "moe", "head_loss", "optimizer"} <= scopes
