@@ -1,4 +1,4 @@
-"""Jit'd public wrapper for the flash-attention kernel (GQA layout).
+"""Public entry of the flash-attention training kernels (GQA layout).
 
 ``interpret=None`` (default) auto-detects the backend: compiled on TPU,
 interpreted elsewhere (``kernels.common``).
@@ -6,35 +6,32 @@ interpreted elsewhere (``kernels.common``).
 
 from __future__ import annotations
 
-import functools
+from ..common import resolve_interpret
+from .flash_attention import Blocks, block_sizes, flash_attention_vjp
 
-import jax
-import jax.numpy as jnp
-
-from .flash_attention import flash_attention_fwd
-
-__all__ = ["flash_attention", "flash_attention_gqa"]
+__all__ = ["block_sizes", "flash_attention_train"]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("causal", "window", "block_q", "block_k", "interpret")
-)
-def flash_attention(q, k, v, *, causal=True, window=0, block_q=128, block_k=128,
-                    interpret=None):
-    """(BH, S, D) attention via the Pallas kernel."""
-    return flash_attention_fwd(
-        q, k, v, causal=causal, window=window, block_q=block_q, block_k=block_k,
-        interpret=interpret,
-    )
+def flash_attention_train(q, k, v, *, causal=True, window=0, block_q=None,
+                          block_k=None, interpret=None):
+    """softmax(q kᵀ / sqrt(D)) v, differentiable, for q (B, S, H, D) and
+    k, v (B, S, KVH, D) with H a multiple of KVH; returns (B, S, H, D).
 
-
-def flash_attention_gqa(q, k, v, *, causal=True, window=0, **kw):
-    """(B, S, H, D) x (B, S, KVH, D) GQA convenience wrapper."""
+    ``window`` > 0 also masks keys ``window`` or more positions before the
+    query. ``block_q``/``block_k`` set the q and kv rows of every block,
+    forward and backward; by default :func:`block_sizes` picks them. ``S``
+    must be a multiple of each.
+    """
     b, s, h, d = q.shape
     kvh = k.shape[2]
-    groups = h // kvh
-    k = jnp.repeat(k, groups, axis=2)
-    v = jnp.repeat(v, groups, axis=2)
-    fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    out = flash_attention(fold(q), fold(k), fold(v), causal=causal, window=window, **kw)
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    if h % kvh:
+        raise ValueError(f"{h} query heads do not share {kvh} kv heads evenly")
+    fwd, bwd = block_sizes(s, d) or ((s, s), (s, s))
+    if block_q or block_k:
+        fwd = bwd = (block_q or fwd[0], block_k or fwd[1])
+    blocks = Blocks(bool(causal), int(window), fwd, bwd, resolve_interpret(interpret))
+    if any(s % blk for blk in fwd + bwd):
+        raise ValueError(f"sequence length {s} is not a multiple of the blocks {blocks}")
+    out = flash_attention_vjp(q.reshape(b, s, h * d), k.reshape(b, s, kvh * d),
+                              v.reshape(b, s, kvh * d), blocks, h, kvh)
+    return out.reshape(b, s, h, d)

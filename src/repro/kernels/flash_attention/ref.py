@@ -5,7 +5,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["attention_ref"]
+__all__ = ["attention_ref", "attention_ref_gqa"]
 
 
 def attention_ref(q, k, v, *, causal=True, window=0):
@@ -26,3 +26,15 @@ def attention_ref(q, k, v, *, causal=True, window=0):
     any_valid = mask.any(axis=1)[None, :, None]
     probs = jnp.where(any_valid, probs, 0.0)
     return jnp.einsum("bqk,bkd->bqd", probs, v.astype(jnp.float32)).astype(q.dtype)
+
+
+def attention_ref_gqa(q, k, v, *, causal=True, window=0):
+    """q (B, S, H, D), k/v (B, S, KVH, D): each kv head repeated over its
+    group of H // KVH query heads, then :func:`attention_ref`."""
+    b, s, h, d = q.shape
+    groups = h // k.shape[2]
+    fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    out = attention_ref(fold(q), fold(jnp.repeat(k, groups, axis=2)),
+                        fold(jnp.repeat(v, groups, axis=2)),
+                        causal=causal, window=window)
+    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)

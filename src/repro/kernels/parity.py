@@ -29,7 +29,7 @@ from .chunk_gather.ref import chunk_gather_ref, chunk_gather_train_ref
 from .common import round_up
 from .decode_attention.ops import decode_attention
 from .decode_attention.ref import decode_attention_ref
-from .flash_attention.ops import flash_attention
+from .flash_attention.ops import flash_attention_train
 from .flash_attention.ref import attention_ref
 from .ssd_scan.ops import ssd_scan
 from .ssd_scan.ref import ssd_scan_ref
@@ -169,9 +169,11 @@ def run_kernel(case: KernelCase, inputs: tuple, *, interpret=None):
         causal = case.shape[3]
         s = case.shape[1]
         bq = min(64, s)
-        return flash_attention(
-            *inputs, causal=causal, block_q=bq, block_k=bq, interpret=interpret
-        )
+        # (BH, S, D) as BH sequences of one head: (BH, S, 1, D)
+        q, kk, v = (t[:, :, None] for t in inputs)
+        return flash_attention_train(
+            q, kk, v, causal=causal, block_q=bq, block_k=bq, interpret=interpret
+        )[:, :, 0]
     if k == "decode_attention":
         return decode_attention(*inputs, block_k=128, interpret=interpret)
     if k == "ssd_scan":

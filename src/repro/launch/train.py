@@ -19,6 +19,7 @@ and consumes batches from the shared-memory ring (DESIGN.md §11).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import tempfile
 import time
@@ -140,6 +141,20 @@ def _local_metrics(loader, store, stager) -> MetricsRegistry:
     if last_plan is not None:
         reg.register_stats("planner", lambda: last_plan.stats)
     return reg
+
+
+def attention_paths(step_fn, *args) -> "list[str]":
+    """Trace ``step_fn`` on ``args`` and describe each distinct
+    ``attention.path`` event the trace emitted: the path an attention layer
+    takes (``kernel``, ``dense`` or ``chunked``) and its shape. The jitted
+    function keeps the trace, so its first call does not trace again."""
+    ring = trace.get()
+    with (contextlib.nullcontext(ring) if ring is not None else trace.tracing()) as t:
+        step_fn.trace(*args)
+        events = [ev[5] for ev in t.events() if ev[0] == "attention.path"]
+    return list(dict.fromkeys(
+        f"{a['path']} (b={a['b']} s={a['s']} h={a['h']} kvh={a['kvh']})" for a in events
+    ))
 
 
 def main(argv=None, *, on_step=None) -> int:
@@ -296,6 +311,8 @@ def main(argv=None, *, on_step=None) -> int:
             if run_steps == 0:
                 # for tools that map a profile's device ops to name scopes
                 programs.note("train_step", step_fn, state, feed)
+                for path in attention_paths(step_fn, state, feed):
+                    print(f"attention path: {path}")
             started = time.perf_counter()
             # The step's dispatch only: the device runs it asynchronously,
             # so tracing adds no sync. The device's own time is the

@@ -9,8 +9,14 @@ count does not divide the TP degree (phi3: 40, llava: 56) set
 K/V are gathered, a context-parallel fallback that keeps compute balanced
 at the price of an all-gather (visible in the roofline collective term).
 
-The chunked path is the pure-jnp oracle for ``kernels/flash_attention``;
-the Pallas kernel replaces it on real TPUs (config ``use_pallas``).
+Train and prefill take ``kernels/flash_attention`` where the step is
+lowered for a TPU and the kernel applies: causal, no logit soft-cap, head
+size and sequence length on the kernel's tiling, and no active mesh that
+splits q/k/v over more than one device (``_kernel_applies``). Everywhere
+else, the CPU included, the pure-jnp paths run: dense up to
+``attn_dense_threshold``, chunked online-softmax above it. Decode is
+always jnp. Tracing ``attention_block`` records the path it takes on the
+process's default backend as an ``attention.path`` instant.
 """
 
 from __future__ import annotations
@@ -18,7 +24,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..parallel.axes import shard
+from ..kernels.flash_attention.ops import block_sizes, flash_attention_train
+from ..obs import trace
+from ..parallel.axes import current_ctx, shard
 from .common import Param, apply_rope, make_rope, scaled_init
 
 __all__ = ["init_attention", "attention_block", "decode_attention_block"]
@@ -140,9 +148,9 @@ def _chunked_attention_vecq(q, k, v, cfg):
 def _chunked_attention(q, k, v, cfg):
     """Online-softmax over KV chunks, queries blocked — O(S·chunk) memory.
 
-    This is the flash-attention recurrence in pure jnp (the ref oracle for
-    the Pallas kernel). Causal masking is applied per chunk pair; the XLA
-    path computes masked blocks too (see DESIGN.md roofline notes).
+    This is the flash-attention recurrence in pure jnp. Causal masking is
+    applied per chunk pair; the XLA path computes masked blocks too (see
+    DESIGN.md roofline notes), which the Pallas kernel skips.
     """
     blk = min(cfg.attn_chunk, q.shape[1])
     b, s, h, d = q.shape
@@ -195,16 +203,9 @@ def _chunked_attention(q, k, v, cfg):
     return blocks.transpose(1, 0, 2, 3, 4).reshape(b, s, h, d)
 
 
-def attention_block(p, x, cfg, *, positions=None):
-    """Full-sequence attention (train / prefill). Returns (out, (k, v))."""
-    b, s, _ = x.shape
-    q, k, v = _project_qkv(p, x, cfg)
-    if positions is None:
-        positions = jnp.arange(s)[None, :]
-    sin, cos = make_rope(positions, cfg.head_dim_, cfg.rope_theta)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
-    kv = (k, v)
+def _jnp_attention(q, k, v, cfg):
+    """The pure-jnp paths: kv heads repeated, dense or chunked."""
+    s = q.shape[1]
     k = _expand_kv(k, cfg)
     v = _expand_kv(v, cfg)
     axes = _qkv_axes(cfg)
@@ -215,7 +216,54 @@ def attention_block(p, x, cfg, *, positions=None):
         out = _chunked_attention_vecq(q, k, v, cfg)
     else:
         out = _chunked_attention(q, k, v, cfg)
-    out = shard(out, *axes)
+    return shard(out, *axes)
+
+
+def _kernel_applies(q, k, cfg) -> bool:
+    """Whether the flash-attention kernel computes exactly this attention on
+    one device: causal, unsoftcapped, on the kernel's tiling, unsharded."""
+    if not cfg.causal or cfg.logit_softcap:
+        return False
+    if block_sizes(q.shape[1], q.shape[3]) is None:
+        return False
+    ctx = current_ctx()
+    if ctx is None:
+        return True
+    axes = _qkv_axes(cfg)
+    return all(
+        ctx.sharding_for(axes, t.shape).shard_shape(t.shape) == t.shape
+        for t in (q, k)
+    )
+
+
+def _record_path(path, q, k):
+    b, s, h, _ = q.shape
+    trace.instant("attention.path", "compute", path=path, b=b, s=s, h=h,
+                  kvh=k.shape[2])
+
+
+def attention_block(p, x, cfg, *, positions=None):
+    """Full-sequence attention (train / prefill). Returns (out, (k, v))."""
+    b, s, _ = x.shape
+    q, k, v = _project_qkv(p, x, cfg)
+    if positions is None:
+        positions = jnp.arange(s)[None, :]
+    sin, cos = make_rope(positions, cfg.head_dim_, cfg.rope_theta)
+    q = apply_rope(q, sin, cos)
+    k = apply_rope(k, sin, cos)
+    kv = (k, v)
+    jnp_path = "dense" if s <= cfg.attn_dense_threshold else "chunked"
+    if _kernel_applies(q, k, cfg):
+        _record_path("kernel" if jax.default_backend() == "tpu" else jnp_path, q, k)
+        out = jax.lax.platform_dependent(
+            q, k, v,
+            tpu=lambda q, k, v: flash_attention_train(
+                q, k, v, causal=True, window=cfg.window, interpret=False),
+            default=lambda q, k, v: _jnp_attention(q, k, v, cfg),
+        )
+    else:
+        _record_path(jnp_path, q, k)
+        out = _jnp_attention(q, k, v, cfg)
     out = jnp.einsum(
         "bsn,nd->bsd", out.reshape(b, s, cfg.num_heads * cfg.head_dim_), p["wo"]
     )

@@ -12,6 +12,7 @@ real TPU the identical suite exercises the compiled kernels.
 """
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -21,8 +22,8 @@ from repro.kernels.chunk_gather.ref import chunk_gather_train_ref
 from repro.kernels.common import resolve_interpret
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
-from repro.kernels.flash_attention.ops import flash_attention, flash_attention_gqa
-from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.flash_attention.ops import flash_attention_train
+from repro.kernels.flash_attention.ref import attention_ref_gqa
 from repro.kernels.ssd_scan.ops import ssd_scan
 
 pytestmark = pytest.mark.kernels
@@ -60,33 +61,96 @@ def test_interpret_auto_detection():
 
 
 # --------------------------------------------------- flash_attention extras
+def _qkv(b, s, h, kvh, d, dtype=jnp.float32):
+    return tuple(jnp.asarray(RNG.normal(size=(b, s, n, d)), dtype) for n in (h, kvh, kvh))
+
+
+def _scaled_err(out, ref):
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    return float(np.max(np.abs(out - ref))) / (float(np.max(np.abs(ref))) + 1e-6)
+
+
+def _out_and_grads(attn, q, k, v, **kw):
+    """The output, and the gradients of q, k, v for a fixed random
+    cotangent of the output."""
+    w = np.random.default_rng(3).normal(size=q.shape)
+
+    @jax.jit
+    def run(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, **kw), q, k, v)
+        return (out, *vjp(jnp.asarray(w, out.dtype)))
+
+    return run(q, k, v)
+
+
+def _ref_f32(q, k, v, **kw):
+    f32 = lambda t: t.astype(jnp.float32)
+    return attention_ref_gqa(f32(q), f32(k), f32(v), **kw)
+
+
+class TestFlashAttentionTraining:
+    """The training kernel (forward and backward) against the float32
+    reference, causal, at head_dim 128: MHA and GQA with 4 query heads per
+    kv head (phi3's ratio)."""
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("s", [256, 512])
+    @pytest.mark.parametrize("h,kvh", [(2, 2), (8, 2)], ids=["mha", "gqa4"])
+    def test_forward_and_gradients(self, h, kvh, s, dtype, tol):
+        q, k, v = _qkv(1, s, h, kvh, 128, dtype)
+        got = _out_and_grads(flash_attention_train, q, k, v, block_q=128, block_k=128)
+        want = _out_and_grads(_ref_f32, q, k, v)
+        for name, g, r in zip(("out", "dq", "dk", "dv"), got, want):
+            assert g.dtype == dtype and g.shape == r.shape, name
+            assert _scaled_err(g, r) <= tol, (name, _scaled_err(g, r))
+
+    def test_matches_dense_attention_path(self):
+        """Same numbers as the model's dense jnp path on its GQA config."""
+        from repro.configs import ModelConfig
+        from repro.models.attention import _dense_attention, _expand_kv
+
+        cfg = ModelConfig(name="t", family="dense", num_layers=1, d_model=1024,
+                          num_heads=8, num_kv_heads=2, d_ff=64, vocab_size=64,
+                          head_dim=128)
+        q, k, v = _qkv(2, 256, 8, 2, 128)
+
+        def dense(q, k, v):
+            return _dense_attention(q, _expand_kv(k, cfg), _expand_kv(v, cfg), cfg)
+
+        got = _out_and_grads(flash_attention_train, q, k, v, block_q=128, block_k=64)
+        for g, r in zip(got, _out_and_grads(dense, q, k, v)):
+            assert _scaled_err(g, r) <= 2e-5
+
+
 class TestFlashAttentionEdges:
     @pytest.mark.parametrize("window", [32, 96, 1024])
     def test_sliding_window(self, window):
-        bh, s, d = 2, 256, 64
-        q, k, v = (jnp.asarray(RNG.normal(size=(bh, s, d)), jnp.float32) for _ in range(3))
-        out = flash_attention(q, k, v, causal=True, window=window, block_q=64, block_k=64)
-        ref = attention_ref(q, k, v, causal=True, window=window)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+        q, k, v = _qkv(2, 256, 1, 1, 64)
+        got = _out_and_grads(flash_attention_train, q, k, v, window=window,
+                             block_q=64, block_k=64)
+        want = _out_and_grads(_ref_f32, q, k, v, window=window)
+        for g, r in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5, rtol=2e-5)
 
     def test_block_shape_independence(self):
-        bh, s, d = 2, 256, 64
-        q, k, v = (jnp.asarray(RNG.normal(size=(bh, s, d)), jnp.float32) for _ in range(3))
+        q, k, v = _qkv(2, 256, 1, 1, 64)
         outs = [
-            flash_attention(q, k, v, block_q=bq, block_k=bk)
+            flash_attention_train(q, k, v, block_q=bq, block_k=bk)
             for bq, bk in [(32, 32), (64, 128), (128, 64), (256, 256)]
         ]
         for o in outs[1:]:
             np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o), atol=1e-5, rtol=1e-5)
 
-    def test_gqa_wrapper(self):
-        b, s, h, kvh, d = 2, 128, 8, 2, 32
-        q = jnp.asarray(RNG.normal(size=(b, s, h, d)), jnp.float32)
-        k = jnp.asarray(RNG.normal(size=(b, s, kvh, d)), jnp.float32)
-        v = jnp.asarray(RNG.normal(size=(b, s, kvh, d)), jnp.float32)
-        out = flash_attention_gqa(q, k, v, block_q=64, block_k=64)
-        assert out.shape == (b, s, h, d)
-        assert np.isfinite(np.asarray(out, np.float32)).all()
+    def test_gqa_native(self):
+        """Grouped kv heads are read through the index map, never repeated,
+        and the kv gradients sum over each group's query heads."""
+        q, k, v = _qkv(2, 128, 8, 2, 32)
+        got = _out_and_grads(flash_attention_train, q, k, v, block_q=64, block_k=64)
+        want = _out_and_grads(_ref_f32, q, k, v)
+        assert got[0].shape == q.shape and got[2].shape == k.shape
+        for g, r in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5, rtol=2e-5)
 
 
 # -------------------------------------------------- decode_attention extras

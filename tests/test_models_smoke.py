@@ -150,3 +150,59 @@ def test_reduced_zamba_has_shared_attention(built):
     cfg, model, values = built("zamba2-1.2b")
     assert "shared_attn" in values
     assert any(k == "shared_attn" for k, _ in cfg.segments())
+
+
+@pytest.mark.parametrize("threshold,path", [(2048, "dense"), (128, "chunked")])
+def test_attention_block_takes_jnp_path_on_cpu(threshold, path, monkeypatch):
+    """A causal config on the kernel's tiling (head_dim 128) still runs the
+    jnp path on the CPU, to the bit, and records the path it takes."""
+    from repro.configs import ModelConfig
+    from repro.models import attention
+    from repro.models.common import RngStream
+    from repro.obs import trace
+
+    cfg = ModelConfig(name="t", family="dense", num_layers=1, d_model=512,
+                      num_heads=4, num_kv_heads=2, d_ff=64, vocab_size=64,
+                      head_dim=128, attn_dense_threshold=threshold)
+    p, _ = split_params(attention.init_attention(RngStream(0), cfg, jnp.float32))
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 256, 512)), jnp.float32)
+
+    def run():  # a new function each time: jit keeps traces by function
+        return jax.jit(lambda p, x: attention.attention_block(p, x, cfg)[0])(p, x)
+
+    with trace.tracing() as t:
+        out = run()
+    events = [ev[5] for ev in t.events() if ev[0] == "attention.path"]
+    assert events == [dict(path=path, b=2, s=256, h=4, kvh=2)]
+    monkeypatch.setattr(attention, "_kernel_applies", lambda *a: False)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(run()))
+
+
+def test_attention_kernel_needs_an_unsplit_causal_layout():
+    """The kernel is taken only where it computes exactly this attention on
+    one device."""
+    import dataclasses
+
+    from jax.sharding import AbstractMesh
+
+    from repro.configs import ModelConfig
+    from repro.models.attention import _kernel_applies
+    from repro.parallel.axes import ShardingRules, sharding_ctx
+
+    cfg = ModelConfig(name="t", family="dense", num_layers=1, d_model=512,
+                      num_heads=4, num_kv_heads=2, d_ff=64, vocab_size=64,
+                      head_dim=128)
+    q = jax.ShapeDtypeStruct((2, 256, 4, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, 256, 2, 128), jnp.bfloat16)
+    assert _kernel_applies(q, k, cfg)
+    assert not _kernel_applies(q, k, dataclasses.replace(cfg, causal=False))
+    assert not _kernel_applies(q, k, dataclasses.replace(cfg, logit_softcap=30.0))
+    q64 = jax.ShapeDtypeStruct((2, 256, 8, 64), jnp.bfloat16)
+    assert not _kernel_applies(q64, q64, dataclasses.replace(cfg, head_dim=64))
+    odd = jax.ShapeDtypeStruct((2, 200, 4, 128), jnp.bfloat16)
+    assert not _kernel_applies(odd, odd, cfg)
+    rules = {"batch": "data", "heads": "model"}
+    with sharding_ctx(ShardingRules(AbstractMesh((1, 1), ("data", "model")), rules)):
+        assert _kernel_applies(q, k, cfg)
+    with sharding_ctx(ShardingRules(AbstractMesh((1, 2), ("data", "model")), rules)):
+        assert not _kernel_applies(q, k, cfg)
