@@ -10,6 +10,7 @@ from .shapes import SHAPES, cell_status, get_shape
 from . import (  # noqa: E402
     deepseek_7b,
     deepseek_moe_16b,
+    deepseek_v2_lite,
     hubert_xlarge,
     kimi_k2_1t_a32b,
     llava_next_34b,
@@ -29,6 +30,7 @@ ARCHS: dict[str, ModelConfig] = {
         tinyllama_1_1b,
         zamba2_1_2b,
         deepseek_moe_16b,
+        deepseek_v2_lite,
         kimi_k2_1t_a32b,
         llava_next_34b,
         hubert_xlarge,
@@ -72,6 +74,8 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
             moe_first_dense=min(cfg.moe_first_dense, 1),
             moe_dense_ff=320 if cfg.moe_dense_ff else 0,
         )
+    if cfg.attn_kind == "mla":
+        kw.update(kv_lora_rank=64, qk_nope_dim=32, qk_rope_dim=16, v_head_dim=32)
     if cfg.family == "hybrid":
         kw.update(ssm_state=16, ssm_head_dim=32, attn_every=2)
     if cfg.family == "ssm":

@@ -31,10 +31,16 @@ class ModelConfig:
     moe_num_shared: int = 0      # shared (always-on) experts
     moe_first_dense: int = 0     # leading dense layers in a MoE stack
     moe_dense_ff: int = 0        # d_ff of those dense layers
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # capacity slots of the sharded dispatch
+                                   # (dry-run meshes); one device is dropless
     router_aux_weight: float = 0.01
     moe_impl: str = "gspmd"      # "gspmd" (pjit dispatch) | "a2a" (shard_map
                                  # all-to-all; needs a mesh with a model axis)
+    moe_experts_held: int = 0    # routed experts this device holds (0 = all)
+    moe_expert_offset: int = 0   # global id of the first held expert
+    moe_norm_topk: bool = True   # renormalise the top-k gate weights to 1
+    moe_aux: str = "switch"      # balance loss: "switch" (batch, top-1) |
+                                 # "seq" (DeepSeek-V2: per sequence, all top-k)
 
     # --- SSM / hybrid ---
     ssm_state: int = 0
@@ -47,6 +53,21 @@ class ModelConfig:
     # --- xLSTM ---
     slstm_every: int = 0         # every k-th block is sLSTM (rest mLSTM)
     mlstm_proj_factor: float = 2.0
+
+    # --- multi-head latent attention (MLA, DeepSeek-V2; no q compression) ---
+    attn_kind: str = "gqa"       # "gqa" | "mla"
+    kv_lora_rank: int = 0        # width of the compressed kv latent
+    qk_nope_dim: int = 0         # per-head q/k width without rotary position
+    qk_rope_dim: int = 0         # per-head q/k width with it (k's is shared)
+    v_head_dim: int = 0
+
+    # --- YaRN rotary scaling [arXiv:2309.00071]; factor 0 = plain RoPE ---
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- attention details ---
     causal: bool = True
@@ -78,6 +99,10 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.num_heads
 
     @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.moe_num_experts
+
+    @property
     def d_inner(self) -> int:  # mamba2 inner width
         return self.ssm_expand * self.d_model
 
@@ -97,6 +122,12 @@ class ModelConfig:
         return total
 
     def _attn_params(self, d, hd):
+        if self.attn_kind == "mla":
+            h, r = self.num_heads, self.kv_lora_rank
+            q = d * h * (self.qk_nope_dim + self.qk_rope_dim)
+            kv_a = d * (r + self.qk_rope_dim) + r  # + the latent's norm
+            kv_b = r * h * (self.qk_nope_dim + self.v_head_dim)
+            return q + kv_a + kv_b + h * self.v_head_dim * d
         return d * (self.num_heads * hd) + 2 * d * (self.num_kv_heads * hd) + (
             self.num_heads * hd
         ) * d
@@ -107,7 +138,7 @@ class ModelConfig:
         if kind == "attn_dense_moe":  # leading dense layer inside a MoE model
             return self._attn_params(d, hd) + 3 * d * (self.moe_dense_ff or self.d_ff) + 2 * d
         if kind == "attn_moe":
-            experts = self.moe_num_experts * 3 * d * self.d_ff
+            experts = self.experts_held * 3 * d * self.d_ff
             shared = self.moe_num_shared * 3 * d * self.d_ff
             router = d * self.moe_num_experts
             return self._attn_params(d, hd) + experts + shared + router + 2 * d
@@ -139,7 +170,7 @@ class ModelConfig:
         total = self.param_count()
         d = self.d_model
         routed = (self.num_layers - self.moe_first_dense) * (
-            self.moe_num_experts * 3 * d * self.d_ff
+            self.experts_held * 3 * d * self.d_ff
         )
         active_routed = routed * self.moe_top_k / self.moe_num_experts
         return int(total - routed + active_routed)
@@ -182,7 +213,8 @@ class ModelConfig:
         return out
 
     def supports_decode(self) -> bool:
-        return self.family != "encoder"
+        # MLA trains and prefills; its latent decode cache is not built
+        return self.family != "encoder" and self.attn_kind != "mla"
 
     def supports_long_context(self) -> bool:
         """Sub-quadratic path exists (assignment: run long_500k only then)."""

@@ -1,10 +1,12 @@
 """Flash attention for training, Pallas/TPU: a forward and a backward
 kernel (FlashAttention-2 [arXiv:2307.08691], adapted to the TPU grid model).
 
-Layout: q is (B, S, H*D) and k, v are (B, S, KVH*D), the projections' own
-layout, so no transpose is made around the kernels. A block is one head's
-(rows, D) slice, picked by its block index along the last axis, so D is a
-multiple of 128 on the chip. GQA is native: query head ``h`` reads kv head
+Layout: q is (B, S, H*Dqk), k is (B, S, KVH*Dqk) and v is (B, S, KVH*Dv),
+the projections' own layout, so no transpose is made around the kernels.
+A block is one head's (rows, D) slice, picked by its block index along the
+last axis, so Dqk and Dv are multiples of 128 on the chip; they may differ
+(latent attention's 128 + 64 q/k lanes, zero-padded to 256, over 128-wide
+values), and the softmax scale is the caller's. GQA is native: query head ``h`` reads kv head
 ``h // (H // KVH)`` through the index map, and the backward kernel sums a
 group's query heads into its kv head's gradient block, so k and v are never
 repeated in HBM.
@@ -63,6 +65,7 @@ class Blocks:
     fwd: "tuple[int, int]"
     bwd: "tuple[int, int]"
     interpret: bool
+    scale: float                 # on q·k before the softmax
 
 
 def block_sizes(s: int, d: int) -> "tuple[tuple[int, int], tuple[int, int]] | None":
@@ -208,9 +211,10 @@ def _fwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _forward(q, k, v, blocks: Blocks, heads: int, kv_heads: int):
-    """q (B, S, H*D), k/v (B, S, KVH*D) -> out (B, S, H*D), lse (B, H, 1, S)."""
+    """q (B, S, H*Dqk), k (B, S, KVH*Dqk), v (B, S, KVH*Dv) -> out
+    (B, S, H*Dv), lse (B, H, 1, S)."""
     b, s, hd = q.shape
-    d = hd // heads
+    d, dv = hd // heads, v.shape[2] // kv_heads
     groups = heads // kv_heads
     bq, bk = blocks.fwd
     tables = _fwd_schedule(s, blocks)
@@ -225,20 +229,20 @@ def _forward(q, k, v, blocks: Blocks, heads: int, kv_heads: int):
         return b, h, 0, qi[t]
 
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, blocks=blocks, sm_scale=d**-0.5),
+        functools.partial(_fwd_kernel, blocks=blocks, sm_scale=blocks.scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, heads, len(tables[0])),
             in_specs=[pl.BlockSpec((None, bq, d), q_map),
                       pl.BlockSpec((None, bk, d), kv_map),
-                      pl.BlockSpec((None, bk, d), kv_map)],
-            out_specs=[pl.BlockSpec((None, bq, d), q_map),
+                      pl.BlockSpec((None, bk, dv), kv_map)],
+            out_specs=[pl.BlockSpec((None, bq, dv), q_map),
                        pl.BlockSpec((None, None, 1, bq), lse_map)],
             scratch_shapes=[pltpu.VMEM((bq, LANES), jnp.float32),  # row max
                             pltpu.VMEM((bq, LANES), jnp.float32),  # row sum
-                            pltpu.VMEM((bq, d), jnp.float32)],     # numerator
+                            pltpu.VMEM((bq, dv), jnp.float32)],    # numerator
         ),
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, s, heads * dv), q.dtype),
                    jax.ShapeDtypeStruct((b, heads, 1, s), jnp.float32)],
         compiler_params=_params(),
         interpret=blocks.interpret,
@@ -298,12 +302,12 @@ def _bwd_kernel(kj_ref, g_ref, qi_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _backward(q, k, v, o, lse, do, blocks: Blocks, heads: int, kv_heads: int):
     b, s, hd = q.shape
-    d = hd // heads
+    d, dv = hd // heads, v.shape[2] // kv_heads
     groups = heads // kv_heads
     bq, bk = blocks.bwd
     # di = rowsum(dO * O) per q position and head, a row like lse: (B, H, 1, S)
-    di = jnp.einsum("bshd,bshd->bhs", do.reshape(b, s, heads, d).astype(jnp.float32),
-                    o.reshape(b, s, heads, d).astype(jnp.float32))[:, :, None, :]
+    di = jnp.einsum("bshd,bshd->bhs", do.reshape(b, s, heads, dv).astype(jnp.float32),
+                    o.reshape(b, s, heads, dv).astype(jnp.float32))[:, :, None, :]
     tables = _bwd_schedule(s, blocks, groups)
 
     def q_map(b, kvh, t, kj, g, qi, f):
@@ -319,22 +323,22 @@ def _backward(q, k, v, o, lse, do, blocks: Blocks, heads: int, kv_heads: int):
         return b, 0, kvh
 
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, blocks=blocks, sm_scale=d**-0.5),
+        functools.partial(_bwd_kernel, blocks=blocks, sm_scale=blocks.scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(b, kv_heads, len(tables[0])),
             in_specs=[pl.BlockSpec((None, bq, d), q_map),
                       pl.BlockSpec((None, bk, d), kv_map),
-                      pl.BlockSpec((None, bk, d), kv_map),
-                      pl.BlockSpec((None, bq, d), q_map),
+                      pl.BlockSpec((None, bk, dv), kv_map),
+                      pl.BlockSpec((None, bq, dv), q_map),
                       pl.BlockSpec((None, None, 1, bq), row_map),
                       pl.BlockSpec((None, None, 1, bq), row_map)],
             out_specs=[pl.BlockSpec((None, s, groups * d), group_map),
                        pl.BlockSpec((None, bk, d), kv_map),
-                       pl.BlockSpec((None, bk, d), kv_map)],
+                       pl.BlockSpec((None, bk, dv), kv_map)],
             scratch_shapes=[pltpu.VMEM((groups, s, d), jnp.float32),
                             pltpu.VMEM((bk, d), jnp.float32),
-                            pltpu.VMEM((bk, d), jnp.float32)],
+                            pltpu.VMEM((bk, dv), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -349,8 +353,8 @@ def _backward(q, k, v, o, lse, do, blocks: Blocks, heads: int, kv_heads: int):
 # ------------------------------------------------------------- custom_vjp
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention_vjp(q, k, v, blocks: Blocks, heads: int, kv_heads: int):
-    """Attention of flat q (B, S, H*D) over k, v (B, S, KVH*D), with the
-    backward kernel as its VJP."""
+    """Attention of flat q (B, S, H*Dqk) over k (B, S, KVH*Dqk) and v
+    (B, S, KVH*Dv), with the backward kernel as its VJP."""
     return _forward(q, k, v, blocks, heads, kv_heads)[0]
 
 

@@ -143,18 +143,27 @@ def _local_metrics(loader, store, stager) -> MetricsRegistry:
     return reg
 
 
-def attention_paths(step_fn, *args) -> "list[str]":
-    """Trace ``step_fn`` on ``args`` and describe each distinct
-    ``attention.path`` event the trace emitted: the path an attention layer
-    takes (``kernel``, ``dense`` or ``chunked``) and its shape. The jitted
-    function keeps the trace, so its first call does not trace again."""
+def traced_paths(step_fn, *args) -> "list[str]":
+    """Trace ``step_fn`` on ``args`` and describe each distinct path event
+    the trace emitted: ``attention.path``, the path an attention layer takes
+    (``kernel``, ``dense`` or ``chunked``) and its shape, and ``moe.route``,
+    an expert layer's dispatch. The jitted function keeps the trace, so its
+    first call does not trace again."""
     ring = trace.get()
     with (contextlib.nullcontext(ring) if ring is not None else trace.tracing()) as t:
         step_fn.trace(*args)
-        events = [ev[5] for ev in t.events() if ev[0] == "attention.path"]
-    return list(dict.fromkeys(
-        f"{a['path']} (b={a['b']} s={a['s']} h={a['h']} kvh={a['kvh']})" for a in events
-    ))
+        events = [(ev[0], ev[5]) for ev in t.events()
+                  if ev[0] in ("attention.path", "moe.route")]
+    lines = []
+    for name, a in events:
+        if name == "attention.path":
+            lines.append(f"attention path: {a['path']} (b={a['b']} s={a['s']} h={a['h']} "
+                         f"kvh={a['kvh']} qk={a['qk']} v={a['v']})")
+        else:
+            lines.append(f"moe route: {a['path']} (routed={a['routed']} held={a['held']} "
+                         f"first={a['first']} top_k={a['top_k']} rows={a['rows']} "
+                         f"dropped={a['dropped']})")
+    return list(dict.fromkeys(lines))
 
 
 def main(argv=None, *, on_step=None) -> int:
@@ -274,6 +283,7 @@ def main(argv=None, *, on_step=None) -> int:
 
     step = int(start or 0)
     run_steps = 0
+    pending_pairs = []  # expert pairs of the steps since the last loss read
     # Per-node StepIO grid for the §6 model columns (--trace only). NB:
     # a Tracer is sized by its event count — test identity, not truth.
     io_grid = [[] for _ in range(spec.num_nodes)] if tracer is not None else None
@@ -311,8 +321,8 @@ def main(argv=None, *, on_step=None) -> int:
             if run_steps == 0:
                 # for tools that map a profile's device ops to name scopes
                 programs.note("train_step", step_fn, state, feed)
-                for path in attention_paths(step_fn, state, feed):
-                    print(f"attention path: {path}")
+                for line in traced_paths(step_fn, state, feed):
+                    print(line)
             started = time.perf_counter()
             # The step's dispatch only: the device runs it asynchronously,
             # so tracing adds no sync. The device's own time is the
@@ -327,10 +337,17 @@ def main(argv=None, *, on_step=None) -> int:
             run_steps += 1
             if on_step is not None:
                 on_step(StepEvent(step, batch, metrics, started, stager))
+            if "expert_pairs" in metrics:
+                pending_pairs.append(metrics["expert_pairs"])
             if step % 10 == 0 or step == 1:
-                # Reading the loss waits for the step to finish on the device.
+                # Reading the loss waits for the step to finish on the device;
+                # the earlier steps' expert-pair counts are ready with it.
                 with trace.span("train.loss_sync", "compute", step=step):
-                    loss = float(metrics["loss"])
+                    loss, pairs = jax.device_get((metrics["loss"], pending_pairs))
+                loss = float(loss)
+                for n in pairs:
+                    trace.count("moe.expert_pairs", int(n))
+                pending_pairs.clear()
                 print(f"step {step:4d} loss {loss:.4f} "
                       f"({(time.time()-t0)/step:.2f}s/step)")
             if step % args.ckpt_every == 0:

@@ -1,6 +1,15 @@
-"""GQA attention: dense, chunked (online-softmax), and decode paths.
+"""GQA and latent attention: dense, chunked (online-softmax), and decode paths.
 
 Layouts: activations (B, S, d_model); q (B, S, H, D); k/v (B, S, KVH, D).
+
+Multi-head latent attention (MLA, DeepSeek-V2 [arXiv:2405.04434], no q
+compression; ``cfg.attn_kind == "mla"``): q = x W_q per head as a 128-wide
+part without position and a 64-wide rotary part; x W_kva = [c_kv ‖ k_pe],
+the latent c_kv RMS-normed and expanded per head to [k_nope ‖ v], k_pe
+rotated once and shared by every head. Attention then runs as MHA over
+q·k of 192 lanes and v of 128, with the softmax scale 1/sqrt(192) times
+YaRN's mscale squared. Training computes k and v per head (no latent
+cache); decode is not built for it.
 
 Sharding: by default heads shard over the "model"/tp mesh axis
 (``shard(q, "batch", None, "heads", None)``). Architectures whose head
@@ -16,7 +25,10 @@ splits q/k/v over more than one device (``_kernel_applies``). Everywhere
 else, the CPU included, the pure-jnp paths run: dense up to
 ``attn_dense_threshold``, chunked online-softmax above it. Decode is
 always jnp. Tracing ``attention_block`` records the path it takes on the
-process's default backend as an ``attention.path`` instant.
+process's default backend, with its q·k and v widths, as an
+``attention.path`` instant. On the kernel path MLA's q and k are
+zero-padded to the chip's 128-lane tiling (192 -> 256): zero lanes change
+no score.
 """
 
 from __future__ import annotations
@@ -27,11 +39,12 @@ import jax.numpy as jnp
 from ..kernels.flash_attention.ops import block_sizes, flash_attention_train
 from ..obs import trace
 from ..parallel.axes import current_ctx, shard
-from .common import Param, apply_rope, make_rope, scaled_init
+from .common import Param, apply_rope, make_rope, rms_norm, scaled_init, yarn_mscale
 
 __all__ = ["init_attention", "attention_block", "decode_attention_block"]
 
 NEG_INF = -1e30
+LANES = 128
 
 
 def _qkv_axes(cfg):
@@ -42,6 +55,8 @@ def _qkv_axes(cfg):
 
 
 def init_attention(rng, cfg, dtype):
+    if cfg.attn_kind == "mla":
+        return _init_mla(rng, cfg, dtype)
     d, hd = cfg.d_model, cfg.head_dim_
     h, kvh = cfg.num_heads, cfg.num_kv_heads
     return {
@@ -50,6 +65,51 @@ def init_attention(rng, cfg, dtype):
         "wv": Param(scaled_init(rng.next(), (d, kvh * hd), dtype), ("embed", "kv_flat")),
         "wo": Param(scaled_init(rng.next(), (h * hd, d), dtype, fan_in=h * hd), ("heads_flat", "embed")),
     }
+
+
+def _init_mla(rng, cfg, dtype):
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    return {
+        "wq": Param(scaled_init(rng.next(), (d, h * (dn + dr)), dtype), ("embed", "heads_flat")),
+        "wkv_a": Param(scaled_init(rng.next(), (d, r + dr), dtype), ("embed", None)),
+        "kv_norm": Param(jnp.zeros((r,), dtype), (None,)),
+        "wkv_b": Param(scaled_init(rng.next(), (r, h * (dn + dv)), dtype), (None, "heads_flat")),
+        "wo": Param(scaled_init(rng.next(), (h * dv, d), dtype, fan_in=h * dv),
+                    ("heads_flat", "embed")),
+    }
+
+
+def mla_scale(cfg) -> float:
+    """MLA's softmax scale: 1/sqrt(q·k width), times YaRN's mscale (of
+    ``mscale_all_dim``) squared."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_qkv(p, x, cfg, positions):
+    """q, k (B, S, H, dn + dr) and v (B, S, H, dv) of latent attention."""
+    b, s, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    q = jnp.einsum("bsd,dn->bsn", x, p["wq"]).reshape(b, s, h, dn + dr)
+    kv_a = jnp.einsum("bsd,dn->bsn", x, p["wkv_a"])
+    c_kv = rms_norm(kv_a[..., :r], p["kv_norm"], cfg.norm_eps)
+    kv = jnp.einsum("bsr,rn->bsn", c_kv, p["wkv_b"]).reshape(b, s, h, dn + dv)
+    sin, cos = make_rope(positions, dr, cfg.rope_theta, cfg)
+    q_pe = apply_rope(q[..., dn:], sin, cos)
+    k_pe = apply_rope(kv_a[:, :, None, r:], sin, cos)  # one head, shared
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, h, dr))], axis=-1)
+    return q, k, kv[..., dn:]
+
+
+def _pad_lanes(t):
+    """Zero-pad the last axis to a multiple of the chip's 128 lanes."""
+    pad = -t.shape[-1] % LANES
+    return jnp.pad(t, [(0, 0)] * (t.ndim - 1) + [(0, pad)]) if pad else t
 
 
 def _project_qkv(p, x, cfg):
@@ -67,9 +127,10 @@ def _expand_kv(k, cfg):
     return jnp.repeat(k, groups, axis=2)
 
 
-def _dense_attention(q, k, v, cfg, q_offset=0):
-    """Direct (S_q x S_kv) attention with causal/window masking. fp32 softmax."""
-    scale = cfg.head_dim_ ** -0.5
+def _dense_attention(q, k, v, cfg, q_offset=0, scale=None):
+    """Direct (S_q x S_kv) attention with causal/window masking. fp32 softmax.
+    ``scale`` defaults to 1/sqrt(q's head width)."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if cfg.logit_softcap:
         logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
@@ -86,7 +147,7 @@ def _dense_attention(q, k, v, cfg, q_offset=0):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _chunked_attention_vecq(q, k, v, cfg):
+def _chunked_attention_vecq(q, k, v, cfg, scale):
     """Online-softmax over KV chunks with ALL query blocks vectorised.
 
     Used for ``attn_shard="seq"`` (head count not divisible by TP): the q
@@ -98,13 +159,13 @@ def _chunked_attention_vecq(q, k, v, cfg):
     """
     blk = min(cfg.attn_chunk, q.shape[1])
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     assert s % blk == 0, (s, blk)
     nq = s // blk
-    scale = d**-0.5
     qb = q.reshape(b, nq, blk, h, d)
     qb = shard(qb, "batch", "seq_tp", None, None, None)
     kb = k.reshape(b, nq, blk, h, d)
-    vb = v.reshape(b, nq, blk, h, d)
+    vb = v.reshape(b, nq, blk, h, dv)
 
     def kv_step(state, ki):
         m, l, acc = state
@@ -138,14 +199,14 @@ def _chunked_attention_vecq(q, k, v, cfg):
 
     m0 = jnp.full((b, nq, h, blk), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, nq, h, blk), jnp.float32)
-    a0 = jnp.zeros((b, nq, h, blk, d), jnp.float32)
+    a0 = jnp.zeros((b, nq, h, blk, dv), jnp.float32)
     m0, l0, a0 = (shard(t, "batch", "seq_tp", *([None] * (t.ndim - 2))) for t in (m0, l0, a0))
     (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), jnp.arange(nq))
     out = acc / jnp.maximum(l[..., None], 1e-30)
-    return out.transpose(0, 1, 3, 2, 4).reshape(b, s, h, d).astype(q.dtype)
+    return out.transpose(0, 1, 3, 2, 4).reshape(b, s, h, dv).astype(q.dtype)
 
 
-def _chunked_attention(q, k, v, cfg):
+def _chunked_attention(q, k, v, cfg, scale):
     """Online-softmax over KV chunks, queries blocked — O(S·chunk) memory.
 
     This is the flash-attention recurrence in pure jnp. Causal masking is
@@ -154,13 +215,13 @@ def _chunked_attention(q, k, v, cfg):
     """
     blk = min(cfg.attn_chunk, q.shape[1])
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     assert s % blk == 0, (s, blk)
     nq = s // blk
-    scale = d**-0.5
 
     qb = q.reshape(b, nq, blk, h, d)
     kb = k.reshape(b, nq, blk, h, d)
-    vb = v.reshape(b, nq, blk, h, d)
+    vb = v.reshape(b, nq, blk, h, dv)
 
     def q_block(carry, qi):
         del carry
@@ -194,16 +255,16 @@ def _chunked_attention(q, k, v, cfg):
 
         m0 = jnp.full((b, h, blk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, h, blk), jnp.float32)
-        a0 = jnp.zeros((b, h, blk, d), jnp.float32)
+        a0 = jnp.zeros((b, h, blk, dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), jnp.arange(nq))
         out = acc / jnp.maximum(l[..., None], 1e-30)
-        return None, out.transpose(0, 2, 1, 3).astype(q.dtype)  # (b, blk, h, d)
+        return None, out.transpose(0, 2, 1, 3).astype(q.dtype)  # (b, blk, h, dv)
 
-    _, blocks = jax.lax.scan(q_block, None, jnp.arange(nq))  # (nq, b, blk, h, d)
-    return blocks.transpose(1, 0, 2, 3, 4).reshape(b, s, h, d)
+    _, blocks = jax.lax.scan(q_block, None, jnp.arange(nq))  # (nq, b, blk, h, dv)
+    return blocks.transpose(1, 0, 2, 3, 4).reshape(b, s, h, dv)
 
 
-def _jnp_attention(q, k, v, cfg):
+def _jnp_attention(q, k, v, cfg, scale):
     """The pure-jnp paths: kv heads repeated, dense or chunked."""
     s = q.shape[1]
     k = _expand_kv(k, cfg)
@@ -211,20 +272,29 @@ def _jnp_attention(q, k, v, cfg):
     axes = _qkv_axes(cfg)
     q, k, v = shard(q, *axes), shard(k, *axes), shard(v, *axes)
     if s <= cfg.attn_dense_threshold:
-        out = _dense_attention(q, k, v, cfg)
+        out = _dense_attention(q, k, v, cfg, scale=scale)
     elif cfg.attn_shard == "seq":
-        out = _chunked_attention_vecq(q, k, v, cfg)
+        out = _chunked_attention_vecq(q, k, v, cfg, scale)
     else:
-        out = _chunked_attention(q, k, v, cfg)
+        out = _chunked_attention(q, k, v, cfg, scale)
     return shard(out, *axes)
 
 
-def _kernel_applies(q, k, cfg) -> bool:
+def _qk_lanes(q, cfg) -> int:
+    """The q·k width the kernel takes: MLA's zero-padded to 128 lanes."""
+    d = q.shape[3]
+    return d + (-d % LANES) if cfg.attn_kind == "mla" else d
+
+
+def _kernel_applies(q, k, cfg, v=None) -> bool:
     """Whether the flash-attention kernel computes exactly this attention on
     one device: causal, unsoftcapped, on the kernel's tiling, unsharded."""
     if not cfg.causal or cfg.logit_softcap:
         return False
-    if block_sizes(q.shape[1], q.shape[3]) is None:
+    s = q.shape[1]
+    if block_sizes(s, _qk_lanes(q, cfg)) is None:
+        return False
+    if v is not None and block_sizes(s, v.shape[3]) is None:
         return False
     ctx = current_ctx()
     if ctx is None:
@@ -236,37 +306,41 @@ def _kernel_applies(q, k, cfg) -> bool:
     )
 
 
-def _record_path(path, q, k):
+def _record_path(path, q, k, v):
     b, s, h, _ = q.shape
     trace.instant("attention.path", "compute", path=path, b=b, s=s, h=h,
-                  kvh=k.shape[2])
+                  kvh=k.shape[2], qk=q.shape[3], v=v.shape[3])
 
 
 def attention_block(p, x, cfg, *, positions=None):
     """Full-sequence attention (train / prefill). Returns (out, (k, v))."""
     b, s, _ = x.shape
-    q, k, v = _project_qkv(p, x, cfg)
     if positions is None:
         positions = jnp.arange(s)[None, :]
-    sin, cos = make_rope(positions, cfg.head_dim_, cfg.rope_theta)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
+    if cfg.attn_kind == "mla":
+        q, k, v = _mla_qkv(p, x, cfg, positions)
+        scale = mla_scale(cfg)
+    else:
+        q, k, v = _project_qkv(p, x, cfg)
+        sin, cos = make_rope(positions, cfg.head_dim_, cfg.rope_theta, cfg)
+        q = apply_rope(q, sin, cos)
+        k = apply_rope(k, sin, cos)
+        scale = cfg.head_dim_ ** -0.5
     kv = (k, v)
     jnp_path = "dense" if s <= cfg.attn_dense_threshold else "chunked"
-    if _kernel_applies(q, k, cfg):
-        _record_path("kernel" if jax.default_backend() == "tpu" else jnp_path, q, k)
+    if _kernel_applies(q, k, cfg, v):
+        _record_path("kernel" if jax.default_backend() == "tpu" else jnp_path, q, k, v)
         out = jax.lax.platform_dependent(
             q, k, v,
             tpu=lambda q, k, v: flash_attention_train(
-                q, k, v, causal=True, window=cfg.window, interpret=False),
-            default=lambda q, k, v: _jnp_attention(q, k, v, cfg),
+                _pad_lanes(q), _pad_lanes(k), v, causal=True, window=cfg.window,
+                scale=scale, interpret=False),
+            default=lambda q, k, v: _jnp_attention(q, k, v, cfg, scale),
         )
     else:
-        _record_path(jnp_path, q, k)
-        out = _jnp_attention(q, k, v, cfg)
-    out = jnp.einsum(
-        "bsn,nd->bsd", out.reshape(b, s, cfg.num_heads * cfg.head_dim_), p["wo"]
-    )
+        _record_path(jnp_path, q, k, v)
+        out = _jnp_attention(q, k, v, cfg, scale)
+    out = jnp.einsum("bsn,nd->bsd", out.reshape(b, s, -1), p["wo"])
     return out, kv
 
 
@@ -304,7 +378,7 @@ def decode_attention_block(p, x, cache_k, cache_v, cache_pos, cfg,
     quant = cfg.kv_cache_dtype == "int8"
     q, k, v = _project_qkv(p, x, cfg)
     pos = jnp.full((b, 1), cache_pos, dtype=jnp.int32)
-    sin, cos = make_rope(pos, hd, cfg.rope_theta)
+    sin, cos = make_rope(pos, hd, cfg.rope_theta, cfg)
     q = apply_rope(q, sin, cos)
     k = apply_rope(k, sin, cos)
     write_idx = jnp.mod(cache_pos, s_c)

@@ -17,6 +17,8 @@ __all__ = [
     "rms_norm",
     "make_rope",
     "apply_rope",
+    "rope_inv_freq",
+    "yarn_mscale",
     "normal_init",
     "scaled_init",
 ]
@@ -95,12 +97,49 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (y * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
 
 
-def make_rope(positions: jax.Array, head_dim: int, theta: float) -> tuple:
-    """(sin, cos) tables for the given positions; fp32."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 * mscale * ln(factor) + 1`` (1 for a
+    factor of 1 or less)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_inv_freq(head_dim: int, theta: float, cfg=None) -> np.ndarray:
+    """Inverse frequencies of the ``head_dim // 2`` rotary pairs.
+
+    With ``cfg.yarn_factor`` set, YaRN's blend [arXiv:2309.00071, as in
+    DeepSeek-V2]: pairs that turn fewer than ``beta_slow`` times over the
+    original context are interpolated (frequency / factor), pairs that turn
+    more than ``beta_fast`` times are kept, with a linear ramp between.
+    """
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    extra = 1.0 / (theta ** (np.arange(0, half, dtype=np.float64) / half))
+    factor = getattr(cfg, "yarn_factor", 0.0)
+    if not factor:
+        return extra.astype(np.float32)
+
+    def dim_of(turns):  # pair index that turns ``turns`` times
+        return (head_dim * math.log(cfg.yarn_original_max_pos / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(dim_of(cfg.yarn_beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def make_rope(positions: jax.Array, head_dim: int, theta: float, cfg=None) -> tuple:
+    """(sin, cos) tables for the given positions; fp32. YaRN's tables
+    (``cfg.yarn_factor``) carry its magnitude ratio ``mscale(mscale) /
+    mscale(mscale_all_dim)``."""
+    freqs = rope_inv_freq(head_dim, theta, cfg)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., half)
-    return jnp.sin(angles), jnp.cos(angles)
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if getattr(cfg, "yarn_factor", 0.0):
+        mag = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+               / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+        if mag != 1.0:
+            sin, cos = sin * mag, cos * mag
+    return sin, cos
 
 
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:

@@ -1,21 +1,33 @@
 """Fine-grained MoE: shared + routed experts, top-k token-choice routing.
 
 Follows DeepSeekMoE [arXiv:2401.06066] (deepseek-moe-16b: 2 shared + 64
-routed, top-6) and the same structure at Kimi-K2 scale (384 routed, top-8).
+routed, top-6; DeepSeek-V2-Lite the same with softmax scores left
+unnormalised and a sequence-wise balance loss) and the same structure at
+Kimi-K2 scale (384 routed, top-8).
 
-Dispatch is **sort-based with capacity dropping**, grouped GShard-style by
-batch row: each sequence dispatches its own tokens into per-expert capacity
-slots (``cap = seq·k·cf / E``). Grouping keeps the expert buffers sharded
-along the batch/data axis — a single global dispatch would make the
-(E, cap, d) buffer unshardable over tokens (≈7 TB/device at kimi-k2 scale);
-the grouped buffer is (B, E, cap, d) with B on the data axis and E on the
-model axis (EP). A (tokens, experts, capacity) one-hot GShard dispatch
-einsum was rejected for the same reason (≈4 GB/device in bf16 at kimi
-scale). Under pjit, XLA lowers the batched gather/scatter across the E
-axis into all-to-alls (measured in the roofline; a shard_map variant is a
-§Perf candidate).
+**Expert share** (``moe_block`` on one device): the layer is told which
+experts it holds (``cfg.moe_experts_held`` from ``cfg.moe_expert_offset``),
+routes every token over all ``moe_num_experts``, and computes its own
+experts' part of the result for the tokens routed to them, as one rank of
+an expert-parallel group does (the exchange between ranks is not part of
+it). It is dropless: the (token, expert) pairs are sorted by held expert,
+the other ranks' pairs last, into a buffer of every pair, and the expert
+matmuls are grouped (``jax.lax.ragged_dot``) over the held experts' runs,
+so no pair is left out for capacity. With every expert held it is the
+whole layer. Each expert's weights are drawn from one key per matrix
+folded with the expert's global id, so a share holds exactly the uncut
+model's experts of its ids.
 
-An auxiliary load-balance loss (Switch-style) is returned for training.
+**Capacity dispatch** (``moe_block`` under a multi-device sharding
+context, the sharded dry-run): sort-based with capacity dropping, grouped
+GShard-style by batch row into per-expert slots (``cap = seq·k·cf / E``),
+the (B, E, cap, d) buffer sharded B over data and E over model;
+``moe_block_a2a`` is its shard_map all-to-all variant.
+
+Routing (fp32 scores): softmax over all experts, top-k, optionally
+renormalised (``moe_norm_topk``). The balance
+loss is Switch-style (``moe_aux="switch"``) or DeepSeek-V2's per sequence
+(``"seq"``). Tracing records the dispatch as a ``moe.route`` instant.
 """
 
 from __future__ import annotations
@@ -23,25 +35,31 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..parallel.axes import shard
+from ..obs import trace
+from ..parallel.axes import current_ctx, shard
 from .common import Param, scaled_init
 
-__all__ = ["init_moe", "moe_block"]
+__all__ = ["init_moe", "moe_block", "moe_block_a2a", "route"]
+
+
+def _expert_draw(key, ids, shape, dtype, fan_in):
+    """One (d, f) matrix per global expert id, each from ``key`` folded
+    with its id."""
+    draw = lambda i: jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
+    return (jax.vmap(draw)(ids) / fan_in ** 0.5).astype(dtype)
 
 
 def init_moe(rng, cfg, dtype):
     d, f, e = cfg.d_model, cfg.d_ff, cfg.moe_num_experts
+    ids = cfg.moe_expert_offset + jnp.arange(cfg.experts_held, dtype=jnp.uint32)
     p = {
         "router": Param(scaled_init(rng.next(), (d, e), dtype), ("embed", None)),
-        "wi_gate": Param(
-            scaled_init(rng.next(), (e, d, f), dtype, fan_in=d), ("experts", "embed", None)
-        ),
-        "wi_up": Param(
-            scaled_init(rng.next(), (e, d, f), dtype, fan_in=d), ("experts", "embed", None)
-        ),
-        "wo": Param(
-            scaled_init(rng.next(), (e, f, d), dtype, fan_in=f), ("experts", None, "embed")
-        ),
+        "wi_gate": Param(_expert_draw(rng.next(), ids, (d, f), dtype, d),
+                         ("experts", "embed", None)),
+        "wi_up": Param(_expert_draw(rng.next(), ids, (d, f), dtype, d),
+                       ("experts", "embed", None)),
+        "wo": Param(_expert_draw(rng.next(), ids, (f, d), dtype, f),
+                    ("experts", None, "embed")),
     }
     if cfg.moe_num_shared:
         sf = f * cfg.moe_num_shared
@@ -53,25 +71,115 @@ def init_moe(rng, cfg, dtype):
     return p
 
 
+def route(p, x, cfg):
+    """Scores over all experts for x (B, S, d): ``(top_p, top_e, aux)``,
+    the k gate weights and expert ids per token (B, S, k) and the balance
+    loss. Scores are fp32 (the router's product accumulates in fp32)."""
+    b, s, _ = x.shape
+    e, k = cfg.moe_num_experts, cfg.moe_top_k
+    logits = jnp.einsum("bsd,de->bse", x, p["router"], preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, k)  # (b, s, k)
+    if cfg.moe_norm_topk:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if cfg.moe_aux == "seq":
+        # DeepSeek-V2: per sequence, each expert's picks among the
+        # sequence's s*k, times E / (s*k), against its mean score over the
+        # sequence, summed over experts; mean over sequences.
+        rows = jnp.arange(b)[:, None]
+        picks = jnp.zeros((b, e), jnp.float32).at[rows, top_e.reshape(b, s * k)].add(1.0)
+        aux = jnp.mean(jnp.sum(picks * (e / (s * k)) * probs.mean(axis=1), axis=-1))
+    else:
+        # Switch (eq. 4-6): top-1 share of the batch against the mean score
+        t = b * s
+        density = jnp.zeros((e,), jnp.float32).at[top_e[..., 0].reshape(-1)].add(1.0) / t
+        aux = e * jnp.sum(density * probs.reshape(t, e).mean(axis=0))
+    return top_p, top_e, aux.astype(jnp.float32)
+
+
+def _shared_experts(p, x, cfg, out):
+    if not cfg.moe_num_shared:
+        return out
+    sp_ = p["shared"]
+    hs = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, sp_["wi_gate"]))
+    hs = hs * jnp.einsum("bsd,df->bsf", x, sp_["wi_up"])
+    return out + jnp.einsum("bsf,fd->bsd", hs, sp_["wo"])
+
+
 def moe_block(p, x, cfg):
-    """x: (B, S, d) -> (out, aux_loss)."""
+    """x: (B, S, d) -> (out, aux_loss, pairs): ``pairs`` counts the
+    (token, expert) pairs the held experts computed. The expert share,
+    dropless, on one device; the capacity dispatch under a sharding
+    context over more than one device."""
+    ctx = current_ctx()
+    if ctx is not None and ctx.mesh.size > 1:
+        return _moe_capacity(p, x, cfg)
+    return _moe_share(p, x, cfg)
+
+
+@jax.custom_vjp
+def _permute(x, to, back):
+    """``x[to]`` for a permutation ``to`` whose inverse is ``back``: the
+    gradient is the gather ``g[back]``, not a scatter-add."""
+    return jnp.take(x, to, axis=0)
+
+
+def _permute_fwd(x, to, back):
+    return jnp.take(x, to, axis=0), (to, back)
+
+
+def _permute_bwd(res, g):
+    to, back = res
+    return jnp.take(g, back, axis=0), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _moe_share(p, x, cfg):
+    b, s, d = x.shape
+    k, held = cfg.moe_top_k, cfg.experts_held
+    t = b * s
+    top_p, top_e, aux = route(p, x, cfg)
+    trace.instant("moe.route", "compute", path="share", routed=cfg.moe_num_experts,
+                  held=held, first=cfg.moe_expert_offset, top_k=k, rows=t * k,
+                  dropped=0)
+
+    # (token, expert) pairs in token order -> sorted by held expert, the
+    # pairs of experts held elsewhere last: their group, ``held``, is not
+    # computed, and what ragged_dot leaves in its rows is undefined (the
+    # TPU's kernel does not write them), so those rows are zeroed on the
+    # way in and out, which zeroes them in the backward pass too
+    local = top_e.reshape(t * k) - cfg.moe_expert_offset
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held).astype(jnp.int32)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)       # slot -> pair
+    slot_of = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))                          # pair -> slot
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+    held_rows = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
+
+    xs = _permute(jnp.repeat(x.reshape(t, d), k, axis=0), order, slot_of)
+    with jax.named_scope("experts"):
+        xs = jnp.where(held_rows, xs, 0)
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, p["wi_gate"], sizes))
+        h = h * jax.lax.ragged_dot(xs, p["wi_up"], sizes)
+        ys = jnp.where(held_rows, jax.lax.ragged_dot(h, p["wo"], sizes), 0)
+    y = _permute(ys, slot_of, order).reshape(t, k, d)
+    w = jnp.where(mine, top_p.reshape(t * k), 0.0).reshape(t, k).astype(x.dtype)
+    out = jnp.einsum("tk,tkd->td", w, y).reshape(b, s, d)
+    return _shared_experts(p, x, cfg, out), aux, jnp.sum(sizes)
+
+
+def _moe_capacity(p, x, cfg):
+    """The capacity dispatch (module docstring); all experts held."""
     b, s, d = x.shape
     e, k = cfg.moe_num_experts, cfg.moe_top_k
     cap = max(int(s * k * cfg.capacity_factor / e), 1)
 
-    # --- routing (fp32 for numerics) ---
-    logits = jnp.einsum("bsd,de->bse", x, p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)  # (b, s, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
-
-    # --- aux load-balance loss (Switch eq. 4-6), via scatter (no one-hot) ---
-    t = b * s
-    density = (
-        jnp.zeros((e,), jnp.float32).at[top_e[..., 0].reshape(-1)].add(1.0) / t
-    )
-    router_mean = probs.reshape(t, e).mean(axis=0)
-    aux = e * jnp.sum(density * router_mean)
+    top_p, top_e, aux = route(p, x, cfg)
+    trace.instant("moe.route", "compute", path="capacity", routed=e, held=e,
+                  first=0, top_k=k, rows=b * e * cap, dropped="unknown")
 
     # --- per-row sort-based dispatch with capacity dropping ---
     # All scatters here move 4-byte *integers* (slot maps), never d_model
@@ -124,13 +232,8 @@ def moe_block(p, x, cfg):
         out = out + yj * wj[..., None]
     out = shard(out, "batch", "seq_act", None)
 
-    if cfg.moe_num_shared:
-        sp_ = p["shared"]
-        hs = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, sp_["wi_gate"]))
-        hs = hs * jnp.einsum("bsd,df->bsf", x, sp_["wi_up"])
-        out = out + jnp.einsum("bsf,fd->bsd", hs, sp_["wo"])
-
-    return out, aux.astype(jnp.float32)
+    out = _shared_experts(p, x, cfg, out)
+    return out, aux, jnp.sum(s2v.astype(jnp.int32))
 
 
 # --------------------------------------------------------- shard_map variant
@@ -145,7 +248,7 @@ def moe_block_a2a(p, x, cfg):
     route for the combine — moving tokens·k·d bytes instead of
     tokens·E_shard·cap·d. Two-stage capacity dropping (per (src,dst) pair,
     then per expert) follows GShard practice; with generous capacity the
-    output equals :func:`moe_block` (equivalence-tested).
+    output equals the capacity dispatch of :func:`moe_block` (equivalence-tested).
 
     Requires an active mesh whose "model" axis divides both the sequence
     and the expert count; ``_apply_block`` selects it via
@@ -172,15 +275,9 @@ def moe_block_a2a(p, x, cfg):
     cap_local = max(int(e_sh * cap_pair * cfg.capacity_factor / e_l), 1)
 
     # routing + aux loss on the global view (router weights are replicated)
-    logits = jnp.einsum("bsd,de->bse", x, p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
-    t_all = b * s
-    density = (
-        jnp.zeros((e,), jnp.float32).at[top_e[..., 0].reshape(-1)].add(1.0) / t_all
-    )
-    aux = e * jnp.sum(density * probs.reshape(t_all, e).mean(axis=0))
+    top_p, top_e, aux = route(p, x, cfg)
+    trace.instant("moe.route", "compute", path="a2a", routed=e, held=e_l,
+                  first=0, top_k=k, rows=e_sh * cap_pair, dropped="unknown")
 
     def local_fn(xl, wig, wiu, wo, te, tp):
         """One model-shard: xl (b_l, s_l, d); te/tp (b_l, s_l, k)."""
@@ -249,20 +346,17 @@ def moe_block_a2a(p, x, cfg):
             * sp_s[:, None]
         )
         out_l = jnp.zeros((bl * sl, d), xl.dtype).at[tok_s].add(contrib)
-        return out_l.reshape(bl, sl, d)
+        kept = jax.lax.psum(jnp.sum(keep2.astype(jnp.int32)), tuple(mesh.axis_names))
+        return out_l.reshape(bl, sl, d), kept
 
     spec_x = P(dp if dp else None, "model", None)
     spec_w = P("model", None, None)
-    out = jax.shard_map(
+    out, pairs = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec_x, spec_w, spec_w, spec_w, spec_x, spec_x),
-        out_specs=spec_x,
+        out_specs=(spec_x, P()),
     )(x, p["wi_gate"], p["wi_up"], p["wo"], top_e, top_p)
 
-    if cfg.moe_num_shared:
-        sp_ = p["shared"]
-        hs = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, sp_["wi_gate"]))
-        hs = hs * jnp.einsum("bsd,df->bsf", x, sp_["wi_up"])
-        out = out + jnp.einsum("bsf,fd->bsd", hs, sp_["wo"])
-    return out, aux.astype(jnp.float32)
+    out = _shared_experts(p, x, cfg, out)
+    return out, aux, pairs

@@ -77,14 +77,21 @@ def _init_block(kind: str, rng: RngStream, cfg: ModelConfig, dtype):
     raise ValueError(kind)
 
 
+def _no_aux():
+    """The auxiliary outputs a block adds up: the MoE balance loss and the
+    (token, expert) pairs its held experts computed."""
+    return {"balance": jnp.zeros((), jnp.float32), "expert_pairs": jnp.zeros((), jnp.int32)}
+
+
 def _apply_block(kind, p, x, cfg, state=None):
     """Full-sequence block application.
 
-    Returns (x_out, cache_entry, aux_loss). cache_entry is the KV (for attn
-    kinds) or the final recurrent state (ssm kinds); None in pure train mode
-    consumers (it is still produced — XLA DCEs it when unused).
+    Returns (x_out, cache_entry, aux) with aux as ``_no_aux``. cache_entry
+    is the KV (for attn kinds) or the final recurrent state (ssm kinds);
+    None in pure train mode consumers (it is still produced — XLA DCEs it
+    when unused).
     """
-    aux = jnp.zeros((), jnp.float32)
+    aux = _no_aux()
     if kind in ("attn_mlp", "attn_dense_moe", "shared_attn", "attn_moe"):
         # Named scopes mark the block's parts in the compiled step's op
         # metadata, so a device trace can split the step by them.
@@ -97,8 +104,8 @@ def _apply_block(kind, p, x, cfg, state=None):
             return x + h, kv, aux
         moe_fn = moe_block_a2a if cfg.moe_impl == "a2a" else moe_block
         with jax.named_scope("moe"):
-            h, aux = moe_fn(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg)
-        return x + h, kv, aux
+            h, balance, pairs = moe_fn(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg)
+        return x + h, kv, {"balance": balance, "expert_pairs": pairs}
     if kind == "mamba2":
         h, st = mamba2_block(p["mixer"], rms_norm(x, p["ln"], cfg.norm_eps), cfg,
                              init_state=state)
@@ -125,7 +132,7 @@ def _decode_block(kind, p, x, cache, cache_pos, cfg):
         )
         x = x + h
         if kind == "attn_moe":
-            h, _ = moe_block(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg)
+            h, _, _ = moe_block(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg)
         else:
             h = mlp_block(p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps))
         if quant:
@@ -274,17 +281,18 @@ class Model:
 
     # ------------------------------------------------------------ forward
     def forward(self, values, inputs, *, remat: str = "none", want_cache: bool = False):
-        """Full-sequence pass. Returns (logits, aux, cache_list)."""
+        """Full-sequence pass. Returns (logits, aux, cache_list); aux sums
+        the blocks' ``balance`` loss and ``expert_pairs``."""
         cfg = self.cfg
         x = self._embed_inputs(values, inputs)
-        aux_total = jnp.zeros((), jnp.float32)
+        aux_total = _no_aux()
         caches = []
         for seg, seg_vals in zip(cfg.segments(), values["segments"]):
             kind, count = seg
             if kind == "shared_attn":
                 x, kv, aux = _apply_block(kind, values["shared_attn"], x, cfg)
                 caches.append(self._kv_to_cache(kv) if want_cache else None)
-                aux_total = aux_total + aux
+                aux_total = jax.tree.map(jnp.add, aux_total, aux)
                 continue
 
             def body(carry, lp, kind=kind):
@@ -294,7 +302,7 @@ class Model:
                 # maps to "model" and the residual stream lives sequence-
                 # sharded between blocks (all-gather in, reduce-scatter out).
                 xx = shard(xx, "batch", "seq_act", "embed_act")
-                return (xx, aux_acc + aux), (
+                return (xx, jax.tree.map(jnp.add, aux_acc, aux)), (
                     self._kv_to_cache(cache) if kind in _ATTN_KINDS else cache
                 )
 

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import threading
 
-__all__ = ["compiled_text", "note"]
+__all__ = ["compiled_text", "note", "noted_args"]
 
 _lock = threading.Lock()
 # name -> [jitted fn, abstract args, compiled text or None]
@@ -34,6 +34,14 @@ def note(name: str, fn, *args) -> None:
     entry = [fn, jax.tree.map(abstract, args), None]
     with _lock:
         _programs[name] = entry
+
+
+def noted_args(name: str):
+    """The abstract arguments (shapes, dtypes) the program noted as
+    ``name`` was noted with, or None when none was noted."""
+    with _lock:
+        entry = _programs.get(name)
+    return None if entry is None else entry[1]
 
 
 def compiled_text(name: str) -> "str | None":

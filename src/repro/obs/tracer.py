@@ -22,7 +22,8 @@ While a ``jax.profiler`` trace is active, every span is also written as a
 installed: the program's spans then land on the profiler's host plane, on
 the clock its device ops use, and each span name's count and total
 duration are summed in-process (:func:`profiled`), so a tool that runs the
-profiler can read them without parsing its file. The bridge is looked up
+profiler can read them without parsing its file. Counters
+(:func:`count`) are summed there the same way. The bridge is looked up
 only once some other module has imported JAX, so processes that never
 touch JAX (service children) stay free of it.
 
@@ -50,6 +51,7 @@ from pathlib import Path
 
 __all__ = [
     "Tracer",
+    "count",
     "disable",
     "enable",
     "get",
@@ -269,9 +271,18 @@ def _add_profiled(name: str, dur: float) -> None:
         _profiled[name] = (n + 1, total + dur)
 
 
+def count(name: str, value: float) -> None:
+    """Add ``value`` to the counter ``name`` if a ``jax.profiler`` trace is
+    active: :func:`profiled` then holds ``(times counted, summed value)``
+    under the counter's name, as it sums a span's seconds."""
+    if _profiling():
+        _add_profiled(name, value)
+
+
 def profiled() -> "dict[str, tuple[int, float]]":
     """``{span name: (count, seconds)}`` of the spans begun while a
-    ``jax.profiler`` trace was active in this process."""
+    ``jax.profiler`` trace was active in this process, and ``{counter name:
+    (times counted, summed value)}`` of the counters (:func:`count`)."""
     with _profiled_lock:
         return dict(_profiled)
 

@@ -45,9 +45,12 @@ def build_train_step(model: Model, run_cfg: RunConfig, optimizer: Optimizer):
                 logits,
                 batch["targets"],
                 batch["loss_mask"],
-                aux=aux,
+                aux=aux["balance"],
                 aux_weight=cfg.router_aux_weight if cfg.moe_num_experts else 0.0,
             )
+        if cfg.moe_num_experts:
+            # read with the loss, so counting costs no host sync of its own
+            metrics = dict(metrics, expert_pairs=aux["expert_pairs"])
         return loss, metrics
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)

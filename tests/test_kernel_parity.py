@@ -153,6 +153,57 @@ class TestFlashAttentionEdges:
             np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5, rtol=2e-5)
 
 
+class TestLatentAttentionWidths:
+    """Latent attention's shape: q·k over 256 lanes (128 + 64 rotary,
+    zero-padded to the chip's tiling) against values 128 wide, with the
+    caller's softmax scale, forward and backward against the float32
+    reference."""
+
+    SCALE = 0.1147214  # 192^-0.5 times YaRN's mscale squared (DeepSeek-V2-Lite)
+
+    @staticmethod
+    def _mla_out_and_grads(attn, q, k, v, **kw):
+        w = np.random.default_rng(5).normal(size=q.shape[:3] + v.shape[3:])
+
+        @jax.jit
+        def run(q, k, v):
+            out, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, **kw), q, k, v)
+            return (out, *vjp(jnp.asarray(w, out.dtype)))
+
+        return run(q, k, v)
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("h,kvh", [(2, 2), (4, 2)], ids=["mha", "gqa2"])
+    def test_forward_and_gradients(self, h, kvh, dtype, tol):
+        b, s = 1, 256
+        q, k = (jnp.asarray(RNG.normal(size=(b, s, n, 256)), dtype) for n in (h, kvh))
+        v = jnp.asarray(RNG.normal(size=(b, s, kvh, 128)), dtype)
+        got = self._mla_out_and_grads(flash_attention_train, q, k, v, scale=self.SCALE,
+                                      block_q=128, block_k=128)
+        want = self._mla_out_and_grads(_ref_f32, q, k, v, scale=self.SCALE)
+        assert got[0].shape == (b, s, h, 128)
+        for name, g, r in zip(("out", "dq", "dk", "dv"), got, want):
+            assert g.dtype == dtype and g.shape == r.shape, name
+            assert _scaled_err(g, r) <= tol, (name, _scaled_err(g, r))
+
+    def test_zero_lanes_change_no_score(self):
+        """q and k of 192 lanes padded with zeros to 256 give the attention
+        of the 192, and zero gradients in the padding."""
+        q192, k192 = (jnp.asarray(RNG.normal(size=(1, 256, 2, 192)), jnp.float32)
+                      for _ in range(2))
+        v = jnp.asarray(RNG.normal(size=(1, 256, 2, 128)), jnp.float32)
+        pad = lambda t: jnp.pad(t, ((0, 0), (0, 0), (0, 0), (0, 64)))
+        got = self._mla_out_and_grads(flash_attention_train, pad(q192), pad(k192), v,
+                                      scale=self.SCALE, block_q=128, block_k=128)
+        want = self._mla_out_and_grads(_ref_f32, q192, k192, v, scale=self.SCALE)
+        assert _scaled_err(got[0], want[0]) <= 2e-5
+        for g, r in zip(got[1:3], want[1:3]):
+            assert _scaled_err(g[..., :192], r) <= 2e-5
+            assert float(jnp.max(jnp.abs(g[..., 192:]))) == 0.0
+        assert _scaled_err(got[3], want[3]) <= 2e-5
+
+
 # -------------------------------------------------- decode_attention extras
 class TestDecodeAttentionEdges:
     def test_ring_buffer_mask(self):

@@ -315,6 +315,20 @@ class TestProfilerBridge:
         assert n == len(on_plane) == 3
         assert seconds == pytest.approx(sum(e[2] - e[1] for e in on_plane) / 1e9, abs=1e-3)
 
+    def test_counters_sum_while_the_profiler_runs(self, tmp_path, monkeypatch):
+        """A counter adds its values, and counts its additions, only while a
+        profiler trace is active, as a span's seconds are summed."""
+        monkeypatch.setattr(trace, "_profiled", {})
+        trace.count("moe.expert_pairs", 5)
+
+        def body():
+            for n in (100, 140, 160):
+                trace.count("moe.expert_pairs", n)
+
+        _profiled_spans(tmp_path, body)
+        trace.count("moe.expert_pairs", 7)
+        assert trace.profiled()["moe.expert_pairs"] == (3, 400)
+
 
 class TestPrograms:
     def test_a_noted_program_compiles_again_with_its_scopes(self, monkeypatch):
@@ -337,6 +351,8 @@ class TestPrograms:
         programs.note("step", fn, s, b)
         fn(s, b)
         assert programs.compiled_text("other") is None
+        assert programs.noted_args("other") is None
+        assert [x.shape for x in programs.noted_args("step")] == [(4,), (2, 4)]
         text = programs.compiled_text("step")
         assert text.startswith("HloModule jit_step") and "/attention/tanh" in text
         assert programs.compiled_text("step") is text

@@ -100,11 +100,12 @@ MOE_EQ_SUBPROC = textwrap.dedent(
     x = jnp.asarray(np.random.default_rng(0).normal(size=(4, 64, cfg.d_model)), jnp.float32)
     rules = ShardingRules(mesh, shd.activation_rules(mesh, RunConfig()))
     with mesh, sharding_ctx(rules):
-        ref, aux_r = jax.jit(lambda v, x: moe_block(v, x, cfg))(values, x)
-        a2a, aux_a = jax.jit(lambda v, x: moe_block_a2a(v, x, cfg))(values, x)
+        ref, aux_r, pairs_r = jax.jit(lambda v, x: moe_block(v, x, cfg))(values, x)
+        a2a, aux_a, pairs_a = jax.jit(lambda v, x: moe_block_a2a(v, x, cfg))(values, x)
     err = float(jnp.max(jnp.abs(ref - a2a))) / float(jnp.max(jnp.abs(ref)))
     assert err < 1e-4, err
     assert abs(float(aux_r) - float(aux_a)) < 1e-5
+    assert int(pairs_r) == int(pairs_a) == 4 * 64 * cfg.moe_top_k  # nothing dropped
     print("RESULT ok", err)
     """
 )

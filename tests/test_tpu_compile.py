@@ -7,7 +7,7 @@ catch what it refuses, such as block shapes off the (8, 128) tiling.
 Shapes are the real ones: batch 8 at the train_4k sequence length and at
 the 2048 tokens of the one-chip smoke run, slot rows lane-padded to 128
 columns as ``pack_records`` ships them; the attention kernels at the
-benchmark cells' batch, sequence and heads.
+benchmark cells' batch, sequence, heads and widths.
 """
 
 import os
@@ -151,6 +151,23 @@ def test_flash_attention_train_compiles_for_v5e(one_chip, shape):
 
     compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
         spec(h), spec(kvh), spec(kvh)).compile()
+    assert all(len(ops) == 1 for ops in _kernel_ops(compiled.as_text()).values())
+
+
+def test_latent_attention_compiles_for_v5e(one_chip):
+    """DeepSeek-V2-Lite's cell: B=4, S=4,096, 16 heads, q·k over 256 lanes
+    (192 zero-padded) and values 128 wide, with an explicit scale."""
+    from repro.kernels.flash_attention.ops import flash_attention_train
+
+    b, s, h = 4, 4096, 16
+    spec = lambda d: jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention_train(q, k, v, scale=0.1147214, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        spec(256), spec(256), spec(128)).compile()
     assert all(len(ops) == 1 for ops in _kernel_ops(compiled.as_text()).values())
 
 

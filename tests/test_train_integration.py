@@ -176,4 +176,4 @@ class TestNamedScopes:
 
     def test_moe_kinds_name_their_experts(self):
         scopes = self._scopes(self._compiled("attn_moe").as_text())
-        assert {"attention", "moe", "head_loss", "optimizer"} <= scopes
+        assert {"attention", "moe", "experts", "head_loss", "optimizer"} <= scopes
