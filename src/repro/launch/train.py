@@ -162,7 +162,7 @@ def traced_paths(step_fn, *args) -> "list[str]":
         else:
             lines.append(f"moe route: {a['path']} (routed={a['routed']} held={a['held']} "
                          f"first={a['first']} top_k={a['top_k']} rows={a['rows']} "
-                         f"dropped={a['dropped']})")
+                         f"rows_full={a['rows_full']} dropped={a['dropped']})")
     return list(dict.fromkeys(lines))
 
 
@@ -283,7 +283,7 @@ def main(argv=None, *, on_step=None) -> int:
 
     step = int(start or 0)
     run_steps = 0
-    pending_pairs = []  # expert pairs of the steps since the last loss read
+    pending_moe = []  # (expert pairs, share overflows) of the steps since the last loss read
     # Per-node StepIO grid for the §6 model columns (--trace only). NB:
     # a Tracer is sized by its event count — test identity, not truth.
     io_grid = [[] for _ in range(spec.num_nodes)] if tracer is not None else None
@@ -338,16 +338,17 @@ def main(argv=None, *, on_step=None) -> int:
             if on_step is not None:
                 on_step(StepEvent(step, batch, metrics, started, stager))
             if "expert_pairs" in metrics:
-                pending_pairs.append(metrics["expert_pairs"])
+                pending_moe.append((metrics["expert_pairs"], metrics["moe_overflow"]))
             if step % 10 == 0 or step == 1:
                 # Reading the loss waits for the step to finish on the device;
-                # the earlier steps' expert-pair counts are ready with it.
+                # the earlier steps' MoE counts are ready with it.
                 with trace.span("train.loss_sync", "compute", step=step):
-                    loss, pairs = jax.device_get((metrics["loss"], pending_pairs))
+                    loss, moe = jax.device_get((metrics["loss"], pending_moe))
                 loss = float(loss)
-                for n in pairs:
-                    trace.count("moe.expert_pairs", int(n))
-                pending_pairs.clear()
+                for pairs, overflow in moe:
+                    trace.count("moe.expert_pairs", int(pairs))
+                    trace.count("moe.share_overflow", int(overflow))
+                pending_moe.clear()
                 print(f"step {step:4d} loss {loss:.4f} "
                       f"({(time.time()-t0)/step:.2f}s/step)")
             if step % args.ckpt_every == 0:

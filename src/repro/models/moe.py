@@ -11,12 +11,16 @@ routes every token over all ``moe_num_experts``, and computes its own
 experts' part of the result for the tokens routed to them, as one rank of
 an expert-parallel group does (the exchange between ranks is not part of
 it). It is dropless: the (token, expert) pairs are sorted by held expert,
-the other ranks' pairs last, into a buffer of every pair, and the expert
-matmuls are grouped (``jax.lax.ragged_dot``) over the held experts' runs,
-so no pair is left out for capacity. With every expert held it is the
-whole layer. Each expert's weights are drawn from one key per matrix
-folded with the expert's global id, so a share holds exactly the uncut
-model's experts of its ids.
+the other ranks' pairs last, the held pairs' tokens are gathered into a
+dispatch buffer, and the expert matmuls are grouped
+(``jax.lax.ragged_dot``) over the held experts' runs. The buffer has 1.5x
+the rows that even routing gives the held experts (``_buffer_rows``); a
+layer whose held pairs outnumber them takes a buffer of every pair, so no
+pair is left out for capacity, and reports it (``moe_overflow``). With
+every expert held the buffer has every pair and it is the whole layer.
+Each expert's weights are drawn from one key per matrix folded with the
+expert's global id, so a share holds exactly the uncut model's experts of
+its ids.
 
 **Capacity dispatch** (``moe_block`` under a multi-device sharding
 context, the sharded dry-run): sort-based with capacity dropping, grouped
@@ -27,10 +31,14 @@ the (B, E, cap, d) buffer sharded B over data and E over model;
 Routing (fp32 scores): softmax over all experts, top-k, optionally
 renormalised (``moe_norm_topk``). The balance
 loss is Switch-style (``moe_aux="switch"``) or DeepSeek-V2's per sequence
-(``"seq"``). Tracing records the dispatch as a ``moe.route`` instant.
+(``"seq"``). Tracing records the dispatch as a ``moe.route`` instant, with
+the buffer's rows beside the rows a buffer of every pair would have.
 """
 
 from __future__ import annotations
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -106,10 +114,16 @@ def _shared_experts(p, x, cfg, out):
     return out + jnp.einsum("bsf,fd->bsd", hs, sp_["wo"])
 
 
+def _aux(balance, overflow=0):
+    return {"balance": balance, "moe_overflow": jnp.asarray(overflow, jnp.int32)}
+
+
 def moe_block(p, x, cfg):
-    """x: (B, S, d) -> (out, aux_loss, pairs): ``pairs`` counts the
-    (token, expert) pairs the held experts computed. The expert share,
-    dropless, on one device; the capacity dispatch under a sharding
+    """x: (B, S, d) -> (out, aux, pairs): ``aux`` holds the balance loss
+    (``balance``) and ``moe_overflow``, 1 where the share's held pairs
+    outnumbered its compact buffer and took the full-size one; ``pairs``
+    counts the (token, expert) pairs the held experts computed. The expert
+    share, dropless, on one device; the capacity dispatch under a sharding
     context over more than one device."""
     ctx = current_ctx()
     if ctx is not None and ctx.mesh.size > 1:
@@ -117,58 +131,155 @@ def moe_block(p, x, cfg):
     return _moe_share(p, x, cfg)
 
 
-@jax.custom_vjp
-def _permute(x, to, back):
-    """``x[to]`` for a permutation ``to`` whose inverse is ``back``: the
-    gradient is the gather ``g[back]``, not a scatter-add."""
-    return jnp.take(x, to, axis=0)
+#: Slots of the expert share's compact dispatch buffer per held pair that
+#: even routing would give: a layer whose held pairs outnumber the slots
+#: takes the full-size buffer instead. Of 1.25 to 2.5, 1.5 gave the lowest
+#: mean step on the V2-Lite cell's held pairs per layer (PERF.md §6).
+_HEADROOM = 1.5
 
 
-def _permute_fwd(x, to, back):
-    return jnp.take(x, to, axis=0), (to, back)
+def _buffer_rows(pairs: int, held: int, routed: int) -> int:
+    """Static slots of the share's dispatch buffer: ``_HEADROOM`` times the
+    held experts' even share of the ``pairs``, rounded up to 512 rows, at
+    most every pair (so every pair where every expert is held)."""
+    return min(pairs, math.ceil(_HEADROOM * pairs * held / routed / 512) * 512)
 
 
-def _permute_bwd(res, g):
-    to, back = res
-    return jnp.take(g, back, axis=0), None, None
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _take_rows(t, x, tok):
+    """The dispatch buffer: row ``r`` is ``x[tok[r]]`` (x has ``t`` rows).
+    Its gradient is :func:`_add_rows`."""
+    return jnp.take(x, tok, axis=0)
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _add_rows(t, v, tok):
+    """The combine: each of the ``t`` tokens' sum of its rows of ``v``,
+    added in float32; the transpose of :func:`_take_rows`. A scatter-add of
+    the buffer's rows, which on a v5e beat a gather of every pair's row
+    (PERF.md §6)."""
+    v32 = v.astype(jnp.float32)
+    return jnp.zeros((t, v.shape[1]), jnp.float32).at[tok].add(v32).astype(v.dtype)
+
+
+def _take_rows_fwd(t, x, tok):
+    return _take_rows(t, x, tok), tok
+
+
+def _take_rows_bwd(t, tok, g):
+    return _add_rows(t, g, tok), None
+
+
+def _add_rows_fwd(t, v, tok):
+    return _add_rows(t, v, tok), tok
+
+
+def _add_rows_bwd(t, tok, g):
+    return _take_rows(t, g, tok), None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+_add_rows.defvjp(_add_rows_fwd, _add_rows_bwd)
+
+
+def _share_rows(experts, x, w, order, sizes, rows):
+    """The held experts' part of each token's output, through a dispatch
+    buffer of the first ``rows`` pairs in ``order`` (every held pair must
+    be among them). x (t, d); w (t, k) the pairs' gate weights, zero for
+    pairs held elsewhere; ``order`` the pairs sorted by held expert, the
+    others last; ``sizes`` the held experts' pair counts."""
+    t, k = w.shape
+    tok = order[:rows] // k
+    held_rows = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+    xs = _take_rows(t, x, tok)
+    with jax.named_scope("experts"):
+        # what ragged_dot leaves in the rows past the groups is undefined
+        # (the TPU's kernel does not write them), so those rows are zeroed
+        # on the way in and out, which zeroes them in the backward pass too
+        xs = jnp.where(held_rows, xs, 0)
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, experts["wi_gate"], sizes))
+        h = h * jax.lax.ragged_dot(xs, experts["wi_up"], sizes)
+        ys = jnp.where(held_rows, jax.lax.ragged_dot(h, experts["wo"], sizes), 0)
+    wr = jnp.take(w.reshape(t * k), order[:rows])[:, None].astype(ys.dtype)
+    return _add_rows(t, ys * wr, tok)
+
+
+def _overflows(sizes, rows):
+    return jnp.sum(sizes) > rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _share(rows, experts, x, w, order, sizes):
+    """:func:`_share_rows` through ``rows`` slots, or through one for every
+    pair where the held pairs outnumber them: dropless either way.
+
+    The backward pass derives the taken branch's VJP again from the inputs,
+    so it runs that branch's forward again. Under full remat the layer does
+    that anyway; under ``remat="dots"`` plain autodiff recomputes the
+    grouped matmuls too, since ``ragged_dot`` is not a ``dot_general``
+    (the V2-Lite cell's step compiled for a v5e, either policy: the same 24
+    grouped-matmul ops as a plain ``lax.cond``, no more FLOPs, and 4.6 GB
+    fewer temporaries, PERF.md §6)."""
+    return jax.lax.cond(_overflows(sizes, rows),
+                        functools.partial(_share_rows, rows=w.size),
+                        functools.partial(_share_rows, rows=rows),
+                        experts, x, w, order, sizes)
+
+
+def _share_fwd(rows, experts, x, w, order, sizes):
+    return _share(rows, experts, x, w, order, sizes), (experts, x, w, order, sizes)
+
+
+def _share_bwd(rows, res, g):
+    # The branch's own VJP, derived again from its inputs: ``cond``'s
+    # linearisation would carry the full-size branch's residuals, zeroed,
+    # through the compact one (the V2-Lite cell's step under full remat,
+    # compiled for a v5e: 12.2 GB of temporaries against 7.5 GB).
+    experts, x, w, order, sizes = res
+
+    def pull(n_rows):
+        def f(experts, x, w):
+            _, vjp = jax.vjp(lambda e, x, w: _share_rows(e, x, w, order, sizes, n_rows),
+                             experts, x, w)
+            return vjp(g)
+        return f
+
+    grads = jax.lax.cond(_overflows(sizes, rows), pull(w.size), pull(rows), experts, x, w)
+    return (*grads, None, None)
+
+
+_share.defvjp(_share_fwd, _share_bwd)
 
 
 def _moe_share(p, x, cfg):
     b, s, d = x.shape
-    k, held = cfg.moe_top_k, cfg.experts_held
+    e, k, held = cfg.moe_num_experts, cfg.moe_top_k, cfg.experts_held
     t = b * s
+    rows = _buffer_rows(t * k, held, e)
     top_p, top_e, aux = route(p, x, cfg)
-    trace.instant("moe.route", "compute", path="share", routed=cfg.moe_num_experts,
-                  held=held, first=cfg.moe_expert_offset, top_k=k, rows=t * k,
+    trace.instant("moe.route", "compute", path="share", routed=e, held=held,
+                  first=cfg.moe_expert_offset, top_k=k, rows=rows, rows_full=t * k,
                   dropped=0)
 
     # (token, expert) pairs in token order -> sorted by held expert, the
-    # pairs of experts held elsewhere last: their group, ``held``, is not
-    # computed, and what ragged_dot leaves in its rows is undefined (the
-    # TPU's kernel does not write them), so those rows are zeroed on the
-    # way in and out, which zeroes them in the backward pass too
+    # pairs of experts held elsewhere last (their group, ``held``, is not
+    # computed): the held pairs fill the buffer's first rows
     local = top_e.reshape(t * k) - cfg.moe_expert_offset
     mine = (local >= 0) & (local < held)
     group = jnp.where(mine, local, held).astype(jnp.int32)
-    order = jnp.argsort(group, stable=True).astype(jnp.int32)       # slot -> pair
-    slot_of = jnp.zeros_like(order).at[order].set(
-        jnp.arange(t * k, dtype=jnp.int32))                          # pair -> slot
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)       # row -> pair
     sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
-    held_rows = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
-
-    xs = _permute(jnp.repeat(x.reshape(t, d), k, axis=0), order, slot_of)
-    with jax.named_scope("experts"):
-        xs = jnp.where(held_rows, xs, 0)
-        h = jax.nn.silu(jax.lax.ragged_dot(xs, p["wi_gate"], sizes))
-        h = h * jax.lax.ragged_dot(xs, p["wi_up"], sizes)
-        ys = jnp.where(held_rows, jax.lax.ragged_dot(h, p["wo"], sizes), 0)
-    y = _permute(ys, slot_of, order).reshape(t, k, d)
-    w = jnp.where(mine, top_p.reshape(t * k), 0.0).reshape(t, k).astype(x.dtype)
-    out = jnp.einsum("tk,tkd->td", w, y).reshape(b, s, d)
-    return _shared_experts(p, x, cfg, out), aux, jnp.sum(sizes)
+    w = jnp.where(mine, top_p.reshape(t * k), 0.0).reshape(t, k)
+    experts = {name: p[name] for name in ("wi_gate", "wi_up", "wo")}
+    x2 = x.reshape(t, d)
+    if rows == t * k:
+        # every pair fits (every expert held, or a small layer): no choice
+        out, overflow = _share_rows(experts, x2, w, order, sizes, rows), 0
+    else:
+        out = _share(rows, experts, x2, w, order, sizes)
+        overflow = _overflows(sizes, rows)
+    out = _shared_experts(p, x, cfg, out.reshape(b, s, d))
+    return out, _aux(aux, overflow), jnp.sum(sizes)
 
 
 def _moe_capacity(p, x, cfg):
@@ -179,7 +290,8 @@ def _moe_capacity(p, x, cfg):
 
     top_p, top_e, aux = route(p, x, cfg)
     trace.instant("moe.route", "compute", path="capacity", routed=e, held=e,
-                  first=0, top_k=k, rows=b * e * cap, dropped="unknown")
+                  first=0, top_k=k, rows=b * e * cap, rows_full=b * s * k,
+                  dropped="unknown")
 
     # --- per-row sort-based dispatch with capacity dropping ---
     # All scatters here move 4-byte *integers* (slot maps), never d_model
@@ -233,7 +345,7 @@ def _moe_capacity(p, x, cfg):
     out = shard(out, "batch", "seq_act", None)
 
     out = _shared_experts(p, x, cfg, out)
-    return out, aux, jnp.sum(s2v.astype(jnp.int32))
+    return out, _aux(aux), jnp.sum(s2v.astype(jnp.int32))
 
 
 # --------------------------------------------------------- shard_map variant
@@ -277,7 +389,8 @@ def moe_block_a2a(p, x, cfg):
     # routing + aux loss on the global view (router weights are replicated)
     top_p, top_e, aux = route(p, x, cfg)
     trace.instant("moe.route", "compute", path="a2a", routed=e, held=e_l,
-                  first=0, top_k=k, rows=e_sh * cap_pair, dropped="unknown")
+                  first=0, top_k=k, rows=e_sh * cap_pair, rows_full=b * s * k,
+                  dropped="unknown")
 
     def local_fn(xl, wig, wiu, wo, te, tp):
         """One model-shard: xl (b_l, s_l, d); te/tp (b_l, s_l, k)."""
@@ -359,4 +472,4 @@ def moe_block_a2a(p, x, cfg):
     )(x, p["wi_gate"], p["wi_up"], p["wo"], top_e, top_p)
 
     out = _shared_experts(p, x, cfg, out)
-    return out, aux, pairs
+    return out, _aux(aux), pairs

@@ -78,9 +78,11 @@ def _init_block(kind: str, rng: RngStream, cfg: ModelConfig, dtype):
 
 
 def _no_aux():
-    """The auxiliary outputs a block adds up: the MoE balance loss and the
-    (token, expert) pairs its held experts computed."""
-    return {"balance": jnp.zeros((), jnp.float32), "expert_pairs": jnp.zeros((), jnp.int32)}
+    """The auxiliary outputs a block adds up: the MoE balance loss, the
+    (token, expert) pairs its held experts computed and whether its expert
+    share took the full-size dispatch buffer (``moe_overflow``)."""
+    return {"balance": jnp.zeros((), jnp.float32), "expert_pairs": jnp.zeros((), jnp.int32),
+            "moe_overflow": jnp.zeros((), jnp.int32)}
 
 
 def _apply_block(kind, p, x, cfg, state=None):
@@ -104,8 +106,8 @@ def _apply_block(kind, p, x, cfg, state=None):
             return x + h, kv, aux
         moe_fn = moe_block_a2a if cfg.moe_impl == "a2a" else moe_block
         with jax.named_scope("moe"):
-            h, balance, pairs = moe_fn(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg)
-        return x + h, kv, {"balance": balance, "expert_pairs": pairs}
+            h, moe_aux, pairs = moe_fn(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg)
+        return x + h, kv, dict(moe_aux, expert_pairs=pairs)
     if kind == "mamba2":
         h, st = mamba2_block(p["mixer"], rms_norm(x, p["ln"], cfg.norm_eps), cfg,
                              init_state=state)
@@ -282,7 +284,7 @@ class Model:
     # ------------------------------------------------------------ forward
     def forward(self, values, inputs, *, remat: str = "none", want_cache: bool = False):
         """Full-sequence pass. Returns (logits, aux, cache_list); aux sums
-        the blocks' ``balance`` loss and ``expert_pairs``."""
+        the blocks' ``balance`` loss, ``expert_pairs`` and ``moe_overflow``."""
         cfg = self.cfg
         x = self._embed_inputs(values, inputs)
         aux_total = _no_aux()

@@ -50,7 +50,8 @@ def build_train_step(model: Model, run_cfg: RunConfig, optimizer: Optimizer):
             )
         if cfg.moe_num_experts:
             # read with the loss, so counting costs no host sync of its own
-            metrics = dict(metrics, expert_pairs=aux["expert_pairs"])
+            metrics = dict(metrics, expert_pairs=aux["expert_pairs"],
+                           moe_overflow=aux["moe_overflow"])
         return loss, metrics
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)

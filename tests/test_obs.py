@@ -329,6 +329,34 @@ class TestProfilerBridge:
         trace.count("moe.expert_pairs", 7)
         assert trace.profiled()["moe.expert_pairs"] == (3, 400)
 
+    def test_a_moe_share_reports_its_buffer_and_counts_its_overflow(
+            self, tmp_path, monkeypatch, capsys):
+        """A short run of a tiny expert share (2 of 8 experts, top-2, 1,024
+        tokens a step) through the launcher under the profiler: its set-up
+        line gives the compact buffer's rows beside every pair's, and each
+        step's fallbacks are counted at the loss reads (steps 1 and 10)."""
+        import dataclasses
+
+        import jax
+
+        from repro.configs import ARCHS, reduced
+        from repro.launch import train
+
+        cfg = dataclasses.replace(reduced(ARCHS["deepseek-v2-lite-16b"]), name="tiny-share",
+                                  moe_experts_held=2, moe_expert_offset=2)
+        monkeypatch.setitem(ARCHS, "tiny-share", cfg)
+        monkeypatch.setattr(trace, "_profiled", {})
+        argv = ["--arch", "tiny-share", "--full", "--steps", "10", "--ckpt-every", "100",
+                "--batch", "4", "--seq-len", "256", "--num-docs", "64",
+                "--device-path", "stage", "--workdir", str(tmp_path / "run")]
+        with jax.profiler.trace(str(tmp_path / "prof")):
+            assert train.main(argv) == 0
+        assert ("moe route: share (routed=8 held=2 first=2 top_k=2 rows=1024 "
+                "rows_full=2048 dropped=0)") in capsys.readouterr().out
+        assert trace.profiled()["moe.share_overflow"] == (10, 0)
+        count, pairs = trace.profiled()["moe.expert_pairs"]
+        assert count == 10 and 0 < pairs < 10 * 1024
+
 
 class TestPrograms:
     def test_a_noted_program_compiles_again_with_its_scopes(self, monkeypatch):

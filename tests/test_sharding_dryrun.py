@@ -104,7 +104,8 @@ MOE_EQ_SUBPROC = textwrap.dedent(
         a2a, aux_a, pairs_a = jax.jit(lambda v, x: moe_block_a2a(v, x, cfg))(values, x)
     err = float(jnp.max(jnp.abs(ref - a2a))) / float(jnp.max(jnp.abs(ref)))
     assert err < 1e-4, err
-    assert abs(float(aux_r) - float(aux_a)) < 1e-5
+    assert abs(float(aux_r["balance"]) - float(aux_a["balance"])) < 1e-5
+    assert int(aux_r["moe_overflow"]) == int(aux_a["moe_overflow"]) == 0
     assert int(pairs_r) == int(pairs_a) == 4 * 64 * cfg.moe_top_k  # nothing dropped
     print("RESULT ok", err)
     """
